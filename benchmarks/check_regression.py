@@ -129,19 +129,12 @@ def check(fresh: dict, baseline: dict, tolerance: float) -> list:
             failures.append(
                 f"{section}.{key} = {val}: must be > {bound} ({why})")
 
-    # the compile gates: absolute and runner-speed-independent.  A null
-    # compile count means the runtime didn't expose the jit cache counter
-    # (private JAX API) — the bench emits None and the gate SKIPS rather
-    # than failing a dependency upgrade; the latency gates above still
-    # cover the recompile symptom.
+    # the compile gates: absolute and runner-speed-independent
     dh = fresh.get("decode_heavy", {})
     compiles = dh.get("coarse", {}).get("compiles")
     savings = dh.get("compile_savings")
     if "decode_heavy" not in fresh:
         failures.append("decode_heavy: section missing from fresh results")
-    elif compiles is None or savings is None:
-        print("compile counters unavailable in fresh results; "
-              "compile gates skipped")
     else:
         if savings <= 0:
             failures.append(

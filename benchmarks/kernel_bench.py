@@ -1,10 +1,11 @@
 """Kernel benchmarks: era_scan + paged_attention vs their jnp references,
 plus the three ``cleanup_batch`` reclamation backends head-to-head.
 
-Wall-clock on this host measures the INTERPRETED Pallas path (CPU Python
-loop — not meaningful as TPU perf) and the jit'd jnp reference; the
-reported roofline numbers are the analytic VPU/MXU estimates for TPU v5e
-(the target), derived from the same byte/flop counting the dry-run uses.
+Wall-clock times the jit'd jnp references on the host it runs on.  The
+analytic bytes and operations of each kernel call are computed from its
+shapes; they become time estimates only with the published peaks of the
+device the bench runs on (``launch.roofline.PEAKS``, keyed by
+``device_kind``) — on a device without peaks (a CPU) none are printed.
 
 The backend comparison (``bench_cleanup_backends``) is the serving-relevant
 number: scalar is the paper's per-block Python loop, numpy the vectorized
@@ -23,7 +24,17 @@ import numpy as np
 from repro.core.era_table import batched_can_delete
 from repro.kernels import ref
 from repro.kernels.era_scan import INF_ERA32
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import PEAKS
+
+
+def _device_peaks():
+    """Peaks of the device this bench runs on, or None (e.g. a CPU)."""
+    return PEAKS.get(jax.devices()[0].device_kind)
+
+
+def _est_us(amount: float, peak_key: str) -> str:
+    p = _device_peaks()
+    return "n/a" if p is None else f"{amount / p[peak_key] * 1e6:.3f} us"
 
 
 def _time(fn, *args, n=5):
@@ -47,11 +58,10 @@ def bench_era_scan(r=4096, t=512, h=10):
     # R×TH broadcast -> bytes = R·4·2 + TH·4 (res resident in VMEM)
     work_bytes = r * 4 * 2 + t * h * 4
     vpu_ops = r * t * h * 3  # 2 compares + and-reduce per pair
-    print(f"era_scan R={r} T={t} H={h}: jnp-ref on CPU {dt*1e3:.2f} ms; "
-          f"TPU est: mem {work_bytes/HBM_BW*1e6:.2f} us, "
-          f"VPU {vpu_ops/ (PEAK_FLOPS/2) *1e6:.3f} us")
-    return {"cpu_ref_ms": dt * 1e3,
-            "tpu_mem_us": work_bytes / HBM_BW * 1e6}
+    print(f"era_scan R={r} T={t} H={h}: jnp-ref {dt*1e3:.2f} ms; "
+          f"{work_bytes} bytes, {vpu_ops} VPU ops; device est: mem "
+          f"{_est_us(work_bytes, 'hbm_bytes_per_s')}")
+    return {"ref_ms": dt * 1e3, "bytes": work_bytes, "vpu_ops": vpu_ops}
 
 
 def bench_paged_attention(b=8, kh=2, g=4, d=128, bs=16, nblk=64):
@@ -68,11 +78,11 @@ def bench_paged_attention(b=8, kh=2, g=4, d=128, bs=16, nblk=64):
     # decode attention is memory-bound: traffic = K+V blocks touched
     kv_bytes = b * nblk * bs * kh * d * 2 * 4
     flops = b * kh * g * nblk * bs * d * 4
-    print(f"paged_attention B={b} ctx={nblk*bs}: jnp-ref CPU {dt*1e3:.2f} ms;"
-          f" TPU est: mem {kv_bytes/HBM_BW*1e6:.1f} us"
-          f" vs compute {flops/PEAK_FLOPS*1e6:.2f} us -> memory-bound"
-          f" (AI={flops/kv_bytes:.2f})")
-    return {"cpu_ref_ms": dt * 1e3, "tpu_mem_us": kv_bytes / HBM_BW * 1e6,
+    print(f"paged_attention B={b} ctx={nblk*bs}: jnp-ref {dt*1e3:.2f} ms;"
+          f" {kv_bytes} KV bytes, {flops} FLOPs (AI={flops/kv_bytes:.2f});"
+          f" device est: mem {_est_us(kv_bytes, 'hbm_bytes_per_s')}"
+          f" vs compute {_est_us(flops, 'flops_bf16')}")
+    return {"ref_ms": dt * 1e3, "kv_bytes": kv_bytes, "flops": flops,
             "arith_intensity": flops / kv_bytes}
 
 
@@ -105,13 +115,13 @@ def bench_paged_attention_int8(b=8, kh=2, g=4, d=128, bs=16, nblk=64):
     kv_fp = b * nblk * bs * kh * d * 2 * 4
     kv_q8 = b * nblk * (bs * kh * d + kh * 4) * 2  # codes + fp32 scales
     saved = 1 - kv_q8 / kv_fp
-    print(f"paged_attention int8 B={b} ctx={nblk*bs}: jnp-ref CPU "
-          f"fp32 {dt_fp*1e3:.2f} ms vs int8 {dt_q8*1e3:.2f} ms; "
-          f"TPU est mem {kv_fp/HBM_BW*1e6:.1f} -> {kv_q8/HBM_BW*1e6:.1f} us "
-          f"({saved:.0%} HBM bytes saved)")
-    return {"cpu_ref_fp32_ms": dt_fp * 1e3, "cpu_ref_int8_ms": dt_q8 * 1e3,
-            "tpu_mem_fp32_us": kv_fp / HBM_BW * 1e6,
-            "tpu_mem_int8_us": kv_q8 / HBM_BW * 1e6,
+    print(f"paged_attention int8 B={b} ctx={nblk*bs}: jnp-ref "
+          f"fp32 {dt_fp*1e3:.2f} ms vs int8 {dt_q8*1e3:.2f} ms; KV bytes "
+          f"{kv_fp} -> {kv_q8} ({saved:.0%} saved); device est: mem "
+          f"{_est_us(kv_fp, 'hbm_bytes_per_s')} -> "
+          f"{_est_us(kv_q8, 'hbm_bytes_per_s')}")
+    return {"ref_fp32_ms": dt_fp * 1e3, "ref_int8_ms": dt_q8 * 1e3,
+            "kv_bytes_fp32": kv_fp, "kv_bytes_int8": kv_q8,
             "hbm_bytes_saved_frac": saved}
 
 
@@ -158,7 +168,8 @@ def bench_cleanup_backends(rs=(256, 1024, 4096, 16384), t=64, h=10):
 
 
 def run():
-    print("\n### Kernel benchmarks (ref path timed on CPU; TPU analytic)")
+    print(f"\n### Kernel benchmarks on {jax.devices()[0].device_kind} "
+          "(jnp references timed; bytes and operations analytic)")
     return {"era_scan": bench_era_scan(),
             "cleanup_backends": bench_cleanup_backends(),
             "paged_attention": bench_paged_attention(),

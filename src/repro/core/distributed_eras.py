@@ -87,12 +87,8 @@ class DistributedEraClock:
         """
         import jax.numpy as jnp
         import numpy as np
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        try:  # jax >= 0.8
-            from jax import shard_map
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map  # type: ignore
 
         n = mesh.shape[axis]
         local = jnp.full((n,), self.local, jnp.int32)

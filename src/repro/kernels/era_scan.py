@@ -39,16 +39,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
+
 INF_ERA32 = jnp.iinfo(jnp.int32).max
 BLOCK_R = 256    # retired blocks per grid step (8×128-aligned Rb×TH tiles)
 BLOCK_TH = 512   # reservation slots per grid step (128-lane multiple)
-
-
-def _resolve_interpret(interpret):
-    """None = auto: run compiled only where Mosaic can lower (real TPUs)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def _era_scan_kernel(alloc_ref, retire_ref, lo_ref, hi_ref, out_ref):
@@ -117,7 +112,7 @@ def era_scan_interval(alloc_eras: jax.Array, retire_eras: jax.Array,
     lo = jnp.full((1, thp), INF_ERA32, jnp.int32).at[0, :th].set(res_lo)
     hi = jnp.full((1, thp), INF_ERA32, jnp.int32).at[0, :th].set(res_hi)
     out = _era_scan_call(a, t, lo, hi,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=resolve_interpret(interpret))
     return out[:r, 0]
 
 

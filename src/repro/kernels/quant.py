@@ -43,13 +43,17 @@ def _safe(scales: jnp.ndarray) -> jnp.ndarray:
 def dequantize_pool(pool: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
     """int8 pool (N, bs, KH, D) + scales (N, KH) -> fp32 (N, bs, KH, D).
 
+    A lane-packed pool (N, bs, KH*D) dequantizes to the same packed shape.
+
     EXACT mirror of the kernel's fused in-register dequant
     (``k.astype(f32) * k_scale[block, head]``): int8 -> f32 is exact and
     the scalar multiply is one f32 rounding, so materializing this array
     and running the fp kernel is bitwise-identical to the fused path.
     """
-    return (pool.astype(jnp.float32)
-            * jnp.asarray(scales, jnp.float32)[:, None, :, None])
+    n, bs, kh = pool.shape[0], pool.shape[1], scales.shape[-1]
+    out = (pool.reshape(n, bs, kh, -1).astype(jnp.float32)
+           * jnp.asarray(scales, jnp.float32)[:, None, :, None])
+    return out.reshape(pool.shape)
 
 
 def quantize_rows(x: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
@@ -78,13 +82,15 @@ def requantize_blocks(blocks: jnp.ndarray, old_scales: jnp.ndarray,
 
 def scatter_quantized(pool: jnp.ndarray, scales: jnp.ndarray,
                       blk: jnp.ndarray, off: jnp.ndarray,
-                      toks: jnp.ndarray, drop_block) -> tuple:
+                      toks: jnp.ndarray, drop_block, layer=None) -> tuple:
     """Scatter fp K/V rows into an int8 pool under running absmax scales.
 
-    pool (N, bs, KH, D) int8; scales (N, KH) f32; blk/off (B, C) i32
-    destination block/offset per token (``blk == drop_block`` marks a
-    padded row: it updates nothing); toks (B, C, KH, D) fp.
-    Returns (pool, scales) updated.
+    pool (N, bs, KH, D) or lane-packed (N, bs, KH*D) int8; scales (N, KH)
+    f32; blk/off (B, C) i32 destination block/offset per token
+    (``blk == drop_block`` marks a padded row: it updates nothing); toks
+    (B, C, KH, D) fp.  With ``layer`` the pool and scales are the
+    layer-stacked (L, N, ...) / (L, N, KH) arrays and only that layer is
+    written, in place.  Returns (pool, scales) updated.
 
     Three scatters, in an order that keeps duplicates idempotent:
 
@@ -100,14 +106,21 @@ def scatter_quantized(pool: jnp.ndarray, scales: jnp.ndarray,
     past the cached boundary — the scatter skip is structural), so a
     cached block's codes AND scale are written by its producer only.
     """
-    n = pool.shape[0]
+    pre = () if layer is None else (layer,)
+    n = pool.shape[len(pre)]
+    b, c, kh, d = toks.shape
     valid = blk != drop_block
     amax = jnp.max(jnp.abs(toks.astype(jnp.float32)), axis=-1)  # (B, C, KH)
     amax = jnp.where(valid[..., None], amax, 0.0)
-    new_scales = scales.at[blk].max(amax / QMAX, mode="drop")
+    new_scales = scales.at[pre + (blk,)].max(amax / QMAX, mode="drop")
     blk_g = jnp.minimum(blk, n - 1)  # in-bounds gather index for pad rows
-    old_s, new_s = scales[blk_g], new_scales[blk_g]  # (B, C, KH)
-    pool = pool.at[blk].set(
-        requantize_blocks(pool[blk_g], old_s, new_s), mode="drop")
-    pool = pool.at[blk, off].set(quantize_rows(toks, new_s), mode="drop")
+    old_s = scales[pre + (blk_g,)]   # (B, C, KH)
+    new_s = new_scales[pre + (blk_g,)]
+    blocks = pool[pre + (blk_g,)]    # (B, C, bs, ...)
+    blocks = requantize_blocks(blocks.reshape(b, c, -1, kh, d), old_s, new_s)
+    pool = pool.at[pre + (blk,)].set(
+        blocks.reshape(b, c, *pool.shape[len(pre) + 1:]), mode="drop")
+    rows = quantize_rows(toks, new_s).reshape(
+        b, c, *pool.shape[len(pre) + 2:])
+    pool = pool.at[pre + (blk, off)].set(rows, mode="drop")
     return pool, new_scales

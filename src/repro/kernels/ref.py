@@ -2,8 +2,12 @@
 
 These are the semantic ground truth: kernel tests sweep shapes/dtypes and
 assert_allclose against these functions, and the CPU dry-run path uses them
-directly (Pallas kernels lower for TPU only; a config flag selects the
-kernel path on TPU).
+directly (``kernels/platform.py`` selects the kernel on TPU and these
+references everywhere else).
+
+Paged pools are ``(N, bs, KH, D)``; the lane-packed ``(N, bs, KH*D)`` the
+serving steps store (see ``paged_attention``) is accepted unchanged, since
+the references only ever reshape a gathered page to ``(bs, KH, D)``.
 """
 
 from __future__ import annotations
@@ -65,7 +69,7 @@ def paged_attention_chunk_ref(
     way, so an exact bound changes nothing bitwise).
     """
     b, c, kh, g, d = q.shape
-    n, bs, _, _ = k_pool.shape
+    bs = k_pool.shape[1]
     nblk = tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
@@ -151,7 +155,7 @@ def paged_attention_ref(
     is already implied by the length mask.
     """
     b, kh, g, d = q.shape
-    n, bs, _, _ = k_pool.shape
+    bs = k_pool.shape[1]
     nblk = tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 

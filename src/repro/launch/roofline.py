@@ -1,10 +1,11 @@
 """Roofline analysis from the compiled dry-run artifact.
 
-Three terms per (arch × shape × mesh), in seconds (TPU v5e constants):
+Three terms per (arch × shape × mesh), in seconds, from the peaks of the
+target chip (``PEAKS``, keyed by ``jax.Device.device_kind``):
 
-    compute    = HLO_FLOPs_per_device / peak_FLOPs        (197 TFLOP/s bf16)
-    memory     = HLO_bytes_per_device / HBM_bw            (819 GB/s)
-    collective = wire_bytes_per_device / link_bw          (~50 GB/s/link)
+    compute    = HLO_FLOPs_per_device / peak_FLOPs        (v5e: 197 TFLOP/s)
+    memory     = HLO_bytes_per_device / HBM_bw            (v5e: 819 GB/s)
+    collective = wire_bytes_per_device / link_bw          (v5e: 50 GB/s/link)
 
 ``cost_analysis()`` yields per-device FLOPs/bytes (the compiled module is
 the per-device SPMD program).  Collective bytes are NOT in cost_analysis —
@@ -29,9 +30,26 @@ from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 # ------------------------------------------------------- hardware constants
-PEAK_FLOPS = 197e12  # bf16 per chip, TPU v5e
-HBM_BW = 819e9  # bytes/s per chip
-LINK_BW = 50e9  # bytes/s per ICI link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+#: interconnect (four links: 50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "ops_int8": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bytes_per_s": 819e9,
+                    "ici_link_bytes_per_s": 50e9},
+}
+#: the chip the dry run's production mesh is built from
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip; an unknown device is an error."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}: add them to PEAKS with their "
+                         f"source (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -111,12 +129,14 @@ def parse_collectives(hlo_text: str, n_devices: int) -> Dict[str, Dict]:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   collectives: Dict[str, Dict]) -> Dict[str, float]:
+                   collectives: Dict[str, Dict],
+                   device_kind: str = DRYRUN_DEVICE_KIND) -> Dict[str, float]:
+    p = peaks(device_kind)
     wire = sum(r["wire_bytes"] for r in collectives.values())
     return {
-        "compute_s": flops_per_dev / PEAK_FLOPS,
-        "memory_s": bytes_per_dev / HBM_BW,
-        "collective_s": wire / LINK_BW,
+        "compute_s": flops_per_dev / p["flops_bf16"],
+        "memory_s": bytes_per_dev / p["hbm_bytes_per_s"],
+        "collective_s": wire / p["ici_link_bytes_per_s"],
         "wire_bytes_per_dev": wire,
     }
 
@@ -139,15 +159,17 @@ def model_flops(cfg, shape, n_active: Optional[int] = None) -> float:
 
 
 def mfu_fraction(model_fl: float, flops_per_dev: float, chips: int,
-                 terms: Dict[str, float]) -> Dict[str, float]:
+                 terms: Dict[str, float],
+                 device_kind: str = DRYRUN_DEVICE_KIND) -> Dict[str, float]:
     """Useful-FLOPs fraction of the roofline-limited step time."""
+    peak = peaks(device_kind)["flops_bf16"]
     step_time = max(terms["compute_s"], terms["memory_s"],
                     terms["collective_s"])
     hlo_global = flops_per_dev * chips
     return {
         "useful_flops_ratio": model_fl / hlo_global if hlo_global else 0.0,
         "bound_step_time_s": step_time,
-        "model_flops_time_s": model_fl / (chips * PEAK_FLOPS),
-        "roofline_fraction": (model_fl / (chips * PEAK_FLOPS)) / step_time
+        "model_flops_time_s": model_fl / (chips * peak),
+        "roofline_fraction": (model_fl / (chips * peak)) / step_time
         if step_time else 0.0,
     }

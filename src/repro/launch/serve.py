@@ -1,10 +1,15 @@
 """Serving driver: run the continuous-batching engine on a reduced config
-(CPU-executable) or lower the full-config serve step for the production
-mesh (see launch/dryrun.py for the sweep).
+(CPU-executable), or on the published config at its full widths (bf16
+weights, a pool sized from the device's memory — needs a TPU).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch stablelm-3b \
       --requests 16 --new-tokens 12 --scheme WFE
+
+Published widths on one chip (``chip_smoke.py`` drives the same
+``build_engine``):
+
+  PYTHONPATH=src python -m repro.launch.serve --published --max-len 1088
 
 Sharded multi-worker runtime (one SMR instance per shard, era clocks
 max-merged on step boundaries; K worker threads pipelining device steps):
@@ -36,9 +41,51 @@ import time
 
 import jax
 
-from repro.configs import ALL_ARCHS, get_smoke_config
+from repro.configs import ALL_ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.serve import ServeEngine, ServeRuntime
+from repro.serve.engine import device_pool_blocks
+
+
+def build_engine(arch: str = "stablelm-3b", *, published: bool = False,
+                 seed: int = 0, n_blocks=None, block_size: int = 16,
+                 max_batch: int = 8, chunk_size: int = 16, max_len=None,
+                 kv_dtype=None, **engine_kwargs):
+    """The one way this repo builds a serving engine.
+
+    ``published=False`` takes the arch's reduced smoke config (f32, runs
+    on a CPU).  ``published=True`` takes the published config at its full
+    widths with bf16 weights (``param_dtype`` = the config's ``dtype``),
+    initialised directly at that dtype from ``seed``; ``n_blocks=None``
+    then sizes the pool from the device's memory for requests of up to
+    ``max_len`` tokens (``serve.engine.device_pool_blocks``).
+    Returns ``(cfg, params, engine, pool_info)``; ``pool_info`` is None
+    when ``n_blocks`` was given.
+    """
+    if published:
+        cfg = get_config(arch)
+        cfg = cfg.scaled(param_dtype=cfg.dtype)
+    else:
+        cfg = get_smoke_config(arch)
+    if cfg.use_mla or cfg.is_encoder_decoder or any(
+            k != "attn" for k in cfg.block_pattern):
+        raise ValueError(f"{arch}: the paged engine serves dense "
+                         "full-attention GQA archs (docs/serving.md)")
+    # op by op: jitted, the 32-layer init alone compiles for over a minute
+    params = build_model(cfg).init(jax.random.key(seed))
+    info = None
+    if n_blocks is None:
+        if max_len is None:
+            raise ValueError("a device-sized pool needs max_len")
+        n_blocks, info = device_pool_blocks(
+            cfg, params, block_size=block_size, max_batch=max_batch,
+            chunk_size=chunk_size, max_len=max_len, kv_dtype=kv_dtype)
+    engine = ServeEngine(cfg, params, n_blocks=n_blocks,
+                         block_size=block_size, max_batch=max_batch,
+                         chunk_size=chunk_size, kv_dtype=kv_dtype,
+                         **engine_kwargs)
+    return cfg, params, engine, info
 
 
 def main(argv=None) -> int:
@@ -49,8 +96,19 @@ def main(argv=None) -> int:
     ap.add_argument("--scheme", default="WFE",
                     choices=("WFE", "Crystalline", "HE", "HP", "EBR",
                              "2GEIBR"))
-    ap.add_argument("--n-blocks", type=int, default=64)
-    ap.add_argument("--block-size", type=int, default=4)
+    ap.add_argument("--published", action="store_true",
+                    help="serve the published config at its full widths "
+                         "with bf16 weights (needs a TPU); without "
+                         "--n-blocks the pool is sized from device memory")
+    ap.add_argument("--max-len", type=int, default=1088,
+                    help="longest request (prompt + new tokens) a "
+                         "device-sized pool is planned for")
+    ap.add_argument("--n-blocks", type=int, default=None,
+                    help="pool blocks (default: 64, or device-sized with "
+                         "--published)")
+    ap.add_argument("--block-size", type=int, default=None,
+                    help="tokens per block (default: 4, 16 with "
+                         "--published)")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--force-slow-path", action="store_true",
                     help="WFE max_attempts=1 (paper §5 stress)")
@@ -88,13 +146,12 @@ def main(argv=None) -> int:
                     help="SLO class for submitted requests ('mix' tags "
                          "every other request batch-class: batch admits "
                          "after — and sheds before — interactive)")
-    ap.add_argument("--kv-dtype", default=None, choices=("fp32", "int8"),
+    ap.add_argument("--kv-dtype", default=None,
+                    choices=("fp32", "bf16", "int8"),
                     help="KV pool storage: 'int8' stores pages as "
                          "symmetric per-(block, kv-head) codes with fp32 "
                          "scales and dequantizes inside the attention "
-                         "kernel — half the K/V bytes per decode step, "
-                         "~2x the blocks at fixed pool memory (default: "
-                         "the arch dtype)")
+                         "kernel (default: the arch dtype)")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable the refcounted prefix cache (prompts "
                          "sharing a block-aligned prefix alias the same "
@@ -118,32 +175,32 @@ def main(argv=None) -> int:
                          "docs/robustness.md")
     args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
-    if cfg.use_mla or cfg.is_encoder_decoder or any(
-            k != "attn" for k in cfg.block_pattern):
-        raise SystemExit(f"{args.arch}: the paged engine serves dense "
-                         "full-attention GQA archs (see DESIGN.md §2.1)")
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
+    setup_compile_cache()
     smr_kwargs = {"era_freq": 4, "cleanup_freq": 4}
     if args.force_slow_path and args.scheme == "WFE":
         smr_kwargs["max_attempts"] = 1
-    engine = ServeEngine(cfg, params, n_blocks=args.n_blocks,
-                         block_size=args.block_size,
-                         max_batch=args.max_batch, scheme=args.scheme,
-                         n_shards=args.shards, merge_freq=args.merge_freq,
-                         # chaos mode: every respawned worker burns a fresh
-                         # tid, so armed faults need real registry headroom
-                         max_threads=max(16 if args.fault_spec else 8,
-                                         args.workers + 2),
-                         max_inflight=max(4, args.workers),
-                         chunk_size=args.chunk_size,
-                         token_budget=args.token_budget,
-                         sched_policy=args.sched_policy,
-                         bucket_policy=args.bucket_policy,
-                         prefix_caching=not args.no_prefix_cache,
-                         kv_dtype=args.kv_dtype,
-                         **smr_kwargs)
+    n_blocks = args.n_blocks
+    if n_blocks is None and not args.published:
+        n_blocks = 64
+    block_size = args.block_size or (16 if args.published else 4)
+    cfg, _, engine, info = build_engine(
+        args.arch, published=args.published, n_blocks=n_blocks,
+        block_size=block_size, max_batch=args.max_batch,
+        chunk_size=args.chunk_size, max_len=args.max_len,
+        kv_dtype=args.kv_dtype, scheme=args.scheme,
+        n_shards=args.shards, merge_freq=args.merge_freq,
+        # chaos mode: every respawned worker burns a fresh tid, so
+        # armed faults need real registry headroom
+        max_threads=max(16 if args.fault_spec else 8, args.workers + 2),
+        max_inflight=max(4, args.workers),
+        token_budget=args.token_budget,
+        sched_policy=args.sched_policy,
+        bucket_policy=args.bucket_policy,
+        prefix_caching=not args.no_prefix_cache,
+        **smr_kwargs)
+    if info is not None:
+        print(f"device-sized pool: {info['n_blocks']} blocks x "
+              f"{block_size} tokens ({info['tokens']} tokens)")
     if args.fault_spec:
         from repro.serve.faults import FaultInjector, FaultSpec
         engine.set_fault_injector(FaultInjector(FaultSpec.parse(

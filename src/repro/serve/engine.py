@@ -1,6 +1,6 @@
 """Serving engine: continuous batching + WFE block pool + paged steps.
 
-The full adaptation loop (DESIGN.md §2.1(A)):
+The full adaptation loop (docs/serving.md):
 
   submit() -> scheduler queue -> tick(): admit / allocate blocks (WFE
   alloc_block / bulk alloc_blocks) / protect_step (WFE get_protected, one
@@ -47,12 +47,12 @@ a cached page.  ``drain`` clears the cache first (cache references must
 not pin slots past shutdown), restoring the every-block-freed invariant.
 
 Greedy sampling; each plan kind dispatches through one jitted function.
-``use_kernel=True`` accelerates BOTH compute paths: paged attention takes
-the Pallas kernel AND reclamation takes the Pallas ``era_scan`` backend
-of ``cleanup_batch`` (``cleanup_backend="pallas"``); otherwise the NumPy
-backend vectorizes the scan.  The paged kernels share ``era_scan``'s
-``interpret=None`` auto path: compiled Mosaic on real TPU backends, the
-interpreter on CPU hosts (CI) — nothing hardcodes ``interpret=True``.
+Attention is chosen by platform (``kernels/platform.py``): the compiled
+Pallas paged kernel on TPU — where the jnp reference is refused — and
+the reference elsewhere, where ``use_kernel=True`` runs the kernel in the
+Pallas interpreter.  Reclamation is chosen separately by
+``cleanup_backend``: ``"numpy"`` (default) vectorizes the era scan on the
+host, ``"pallas"`` runs the ``era_scan`` kernel.
 
 Concurrency: ``step()`` is safe to call from many worker threads (the
 ``ServeRuntime`` in ``runtime.py`` does exactly that).  Scheduling and
@@ -85,11 +85,15 @@ import numpy as np
 
 from repro.blocks import (BlockPool, PrefixCache, Scheduler,
                           ShardedBlockPool)
+from repro.kernels import platform
+from repro.kernels.paged_attention import check_int8_scales_fit
 from repro.models.common import ArchConfig
 
-from .paged_model import init_pools, paged_decode_step, paged_prefill_chunk
+from .paged_model import (KV_DTYPES, init_pools, paged_decode_step,
+                          paged_prefill_chunk, pool_shape)
 
-__all__ = ["ServeEngine"]
+__all__ = ["ServeEngine", "device_pool_blocks", "pool_block_bytes",
+           "pool_blocks_that_fit"]
 
 #: era ticks a quiescent drain may need before every scheme must have
 #: reclaimed everything: EBR's two grace periods + one for the stamp round,
@@ -99,22 +103,11 @@ DRAIN_ERA_BOUND = 4
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_step(cfg, use_kernel: bool):
-    """Shared jitted decode step (ArchConfig is frozen/hashable): engines
-    over the same config reuse one compilation cache instead of re-tracing
-    per instance — the scaling benchmark builds a dozen engines."""
-    return jax.jit(
-        lambda params, pools, tables, lengths, tokens, positions:
-        paged_decode_step(cfg, params, pools, tables, lengths, tokens,
-                          positions, use_kernel=use_kernel),
-        donate_argnums=(1,))
-
-
-@functools.lru_cache(maxsize=None)
 def _jit_decode(cfg, use_kernel: bool):
-    """Serve-loop variant with greedy sampling fused into the step: the
-    host pulls back (B,) sampled ids, not (B, vocab) logits, and skips a
-    second dispatch round-trip per token."""
+    """Jitted decode step with greedy sampling fused in: the host pulls
+    back (B,) sampled ids, not (B, vocab) logits.  Shared across engines
+    over one (frozen, hashable) config, so they reuse one compilation
+    cache; the donated pools are updated in place."""
 
     def _decode(params, pools, tables, lengths, tokens, positions):
         logits, pools = paged_decode_step(
@@ -141,10 +134,82 @@ def _jit_prefill(cfg, use_kernel: bool):
     return jax.jit(_prefill, donate_argnums=(1,))
 
 
+def pool_block_bytes(cfg, block_size: int, kv_dtype=None) -> int:
+    """Device bytes of one pool block across all layers: its K and V pages
+    (lane-packed, so no tile padding when KH*D is a multiple of 128) plus
+    the int8 mode's per-(block, kv-head) f32 scales."""
+    dtype = cfg.dtype if kv_dtype is None else KV_DTYPES[kv_dtype]
+    n_layers, _, bs, width = pool_shape(cfg, 1, block_size)
+    total = 2 * n_layers * bs * width * jnp.dtype(dtype).itemsize
+    if dtype == jnp.int8:
+        total += 2 * n_layers * cfg.n_kv_heads * 4
+    return total
+
+
+def pool_blocks_that_fit(bytes_limit: int, resident_bytes: int,
+                         step_temp_bytes: int, block_bytes: int,
+                         headroom: float = 0.10) -> int:
+    """Blocks left by ``bytes_limit`` less ``headroom``, the resident bytes
+    and a step's temporaries — less the one scratch block the engine adds
+    per shard."""
+    free = bytes_limit * (1.0 - headroom) - resident_bytes - step_temp_bytes
+    return int(free // block_bytes) - 1
+
+
+def device_pool_blocks(cfg, params, *, block_size: int, max_batch: int,
+                       chunk_size: int, max_len: int, kv_dtype=None,
+                       headroom: float = 0.10, min_tokens: int = 8192,
+                       probe_blocks: int = 64) -> tuple:
+    """The largest pool the device's memory leaves room for.
+
+    Compiles the decode step and the widest mixed step (``max_batch``
+    decode rows plus one ``chunk_size`` chunk, at the table width of
+    ``max_len`` tokens) against a ``probe_blocks`` pool and takes the
+    larger temporary footprint — the steps update the pools in place, so
+    their temporaries do not grow with the pool.  What is left of
+    ``bytes_limit`` after ``headroom``, the resident bytes (the weights)
+    and those temporaries is divided into blocks of ``pool_block_bytes``.
+    Returns ``(n_blocks, info)``; raises if the device reports no memory
+    statistics or the pool would hold fewer than ``min_tokens`` tokens.
+    """
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(f"{jax.devices()[0]} reports no memory "
+                           "statistics: give n_blocks explicitly")
+    use_kernel = platform.resolve_use_kernel(None)
+    pools = jax.eval_shape(
+        lambda: init_pools(cfg, probe_blocks, block_size, kv_dtype))
+    width = 1 << max(0, -(-max_len // block_size) - 1).bit_length()
+    cb = 1 << max(0, chunk_size - 1).bit_length()
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    temps = []
+    for fn, args in (
+            (_jit_decode(cfg, use_kernel),
+             (i32(max_batch, width), i32(max_batch), i32(max_batch),
+              i32(max_batch))),
+            (_jit_prefill(cfg, use_kernel),
+             (i32(max_batch + 1, width), i32(max_batch + 1, cb),
+              i32(max_batch + 1, cb), i32(max_batch + 1)))):
+        mem = fn.lower(params, pools, *args).compile().memory_analysis()
+        temps.append(mem.temp_size_in_bytes)
+    per_block = pool_block_bytes(cfg, block_size, kv_dtype)
+    n_blocks = pool_blocks_that_fit(stats["bytes_limit"],
+                                    stats["bytes_in_use"], max(temps),
+                                    per_block, headroom)
+    info = dict(bytes_limit=stats["bytes_limit"],
+                resident_bytes=stats["bytes_in_use"],
+                step_temp_bytes=max(temps), block_bytes=per_block,
+                n_blocks=n_blocks, tokens=n_blocks * block_size)
+    if n_blocks * block_size < min_tokens:
+        raise RuntimeError(f"device pool holds {n_blocks * block_size} "
+                           f"tokens, fewer than {min_tokens}: {info}")
+    return n_blocks, info
+
+
 class ServeEngine:
     def __init__(self, cfg: ArchConfig, params, *, n_blocks: int = 64,
                  block_size: int = 8, max_batch: int = 8,
-                 scheme: str = "WFE", use_kernel: bool = False,
+                 scheme: str = "WFE", use_kernel: Optional[bool] = None,
                  cleanup_backend: str = "numpy",
                  max_threads: int = 8, n_shards: int = 1,
                  max_inflight: int = 4, merge_freq: int = 1,
@@ -159,7 +224,14 @@ class ServeEngine:
         self.cfg = cfg
         self.params = params
         self.block_size = block_size
-        self.use_kernel = use_kernel
+        # attention by platform: the Pallas kernel on TPU (never the
+        # reference there), the jnp reference elsewhere unless the kernel
+        # is asked for (then it runs in the Pallas interpreter)
+        self.use_kernel = platform.resolve_use_kernel(use_kernel)
+        if platform.on_tpu() and not self.use_kernel:
+            raise ValueError("use_kernel=False on TPU: the paged kernel "
+                             "serves attention there; the jnp reference "
+                             "is the CPU test oracle")
         # quantized KV mode: ``kv_dtype="int8"`` stores pool pages as
         # symmetric per-(block, kv-head) int8 codes with fp32 scale arrays
         # riding in the pools dict (donated alongside the pages by the
@@ -194,8 +266,7 @@ class ServeEngine:
         self.pad_shapes = pad_shapes
         self.max_batch = max_batch
         pool_kwargs = dict(scheme=scheme, max_threads=max_threads,
-                           cleanup_backend=cleanup_backend,
-                           use_kernel=use_kernel, **smr_kwargs)
+                           cleanup_backend=cleanup_backend, **smr_kwargs)
         self.pool: Union[BlockPool, ShardedBlockPool]
         if n_shards > 1:
             self.pool = ShardedBlockPool(n_blocks, n_shards=n_shards,
@@ -236,6 +307,11 @@ class ServeEngine:
         self.faults = None
         self._inflight_plans: Dict[int, object] = {}
         pad = 1 if pad_shapes else 0
+        if kv_dtype == "int8" and self.use_kernel and platform.on_tpu():
+            # the kernel keeps a layer's scales in scalar memory: refuse a
+            # pool too large for it here, not at the first dispatch
+            check_int8_scales_fit(max(self._shard_sizes) + pad,
+                                  cfg.n_kv_heads)
         # one extra scratch slot per shard absorbs the KV writes of
         # batch-padding rows — it is never handed out by the block pool, so
         # padded steps can't corrupt a live request's pages
@@ -245,42 +321,27 @@ class ServeEngine:
         # per-shard dispatch locks: each serializes one shard's functional
         # KV-pool chain; the wait on the device result happens outside
         self._device_locks = [threading.Lock() for _ in self._shard_sizes]
-        # donated pools: the step's functional KV update writes in place
-        # instead of copying every page each token (CPU hosts)
-        self._step = _jit_step(cfg, use_kernel)
-        self._decode = _jit_decode(cfg, use_kernel)
-        self._prefill = _jit_prefill(cfg, use_kernel)
+        # donated pools: the step's KV scatter writes in place instead of
+        # copying every page each token
+        self._decode = _jit_decode(cfg, self.use_kernel)
+        self._prefill = _jit_prefill(cfg, self.use_kernel)
 
     # ------------------------------------------- compile-cache introspection
-    # the jitted steps are lru-shared across engines over one config, and
-    # their cache counters are private JAX API — keep the probing HERE so
-    # the compile-count perf gate (benchmarks/serve_bench.py) and the
-    # bucket-policy tests degrade together when the API moves
-    def compile_cache_size(self):
-        """Total compiled shape variants of the decode+prefill steps, or
-        None when the runtime doesn't expose the counter."""
-        total = 0
-        for fn in (self._decode, self._prefill):
-            try:
-                total += int(fn._cache_size())
-            except AttributeError:
-                return None
-        return total
+    # the jitted steps are lru-shared across engines over one config; the
+    # compile-count gate (benchmarks/serve_bench.py) and the bucket-policy
+    # tests read their cache counters here
+    def compile_cache_size(self) -> int:
+        """Total compiled shape variants of the decode+prefill steps."""
+        return sum(int(fn._cache_size())
+                   for fn in (self._decode, self._prefill))
 
-    def clear_compile_caches(self) -> bool:
-        """Drop the compiled decode/prefill variants (False if the runtime
-        doesn't support it).  NOTE: shared across engines over one config."""
-        ok = True
+    def clear_compile_caches(self) -> None:
+        """Drop the compiled decode/prefill variants.  NOTE: shared across
+        engines over one config."""
         for fn in (self._decode, self._prefill):
-            clear = getattr(fn, "clear_cache", None)
-            if clear is None:
-                ok = False
-            else:
-                clear()
-        return ok
+            fn.clear_cache()
 
-    # legacy single-shard view of the device pools (tests/benchmarks drive
-    # engine._step with engine.pools directly)
+    # single-shard view of the device pools (tests poison pages through it)
     @property
     def pools(self):
         return self._shard_pools[0]

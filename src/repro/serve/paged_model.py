@@ -1,19 +1,27 @@
 """Decode/prefill steps that read and write the PAGED KV pool.
 
 This is the device side of the WFE adaptation: the host scheduler names
-blocks via tables; the device step gathers K/V through those tables
-(kernels/paged_attention on TPU, jnp ref on CPU) and scatters the new
-token's K/V into the block the table's tail names.
+blocks via tables; the device step scatters each new token's K/V into the
+block the table names and attends through the tables (the Pallas kernel
+on TPU, the jnp reference elsewhere — ``kernels/platform.py``).
 
-Supported stacks: dense GQA attention archs ("attn"/"swa"/"local_attn"
-without MLA).  Recurrent archs keep O(1) state and need no paging; MLA
-would page 576-wide latents with the same mechanics (documented extension).
+Supported stacks: dense GQA full-attention archs (every layer ``"attn"``,
+no MLA).  Windowed layers (``"swa"``/``"local_attn"``) are rejected by
+``_check_paged_support``: the kernel has no window yet.  Recurrent archs
+keep O(1) state and need no paging; MLA pages 576-wide latents through
+``paged_mla_decode_step`` below.
+
+Pools are layer-stacked and lane-packed, ``(L, N, bs, KH*D)`` (see
+``kernels/paged_attention``).  Both steps scan over the layers carrying
+the pools: each layer scatters into ``pools[l, blk, off]`` of the carried
+(donated) arrays, so the update is in place and a step's temporaries do
+not grow with the pool.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -34,8 +42,15 @@ KV_DTYPES = {"fp32": jnp.float32, "fp16": jnp.float16,
              "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
+def pool_shape(cfg, n_blocks: int, block_size: int) -> tuple:
+    """``(L, N, bs, KH*D)``: one token's K (or V) for every kv head packed
+    along the lanes of one pool row."""
+    return (cfg.n_groups * len(cfg.block_pattern), n_blocks, block_size,
+            cfg.n_kv_heads * cfg.resolved_head_dim)
+
+
 def init_pools(cfg, n_blocks: int, block_size: int, kv_dtype=None):
-    """One K and one V pool per stacked group-layer: (L, N, bs, KH, D).
+    """One K and one V pool, layer-stacked: ``pool_shape`` above.
 
     ``kv_dtype`` overrides the pool storage dtype (``KV_DTYPES`` keys;
     None follows ``cfg.dtype``).  ``"int8"`` stores symmetric
@@ -57,55 +72,108 @@ def init_pools(cfg, n_blocks: int, block_size: int, kv_dtype=None):
     so a recycled slot is merely quantized a notch coarser (bounded by
     the largest absmax the slot ever held), never incorrectly.
     """
-    kh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     if kv_dtype is not None and kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype={kv_dtype!r}: expected one of "
                          f"{sorted(KV_DTYPES)} or None")
     dtype = cfg.dtype if kv_dtype is None else KV_DTYPES[kv_dtype]
-    n_layers = cfg.n_groups * len(cfg.block_pattern)
-    shape = (n_layers, n_blocks, block_size, kh, hd)
+    shape = pool_shape(cfg, n_blocks, block_size)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if dtype == jnp.int8:
-        sshape = (n_layers, n_blocks, kh)
+        sshape = shape[:2] + (cfg.n_kv_heads,)
         pools["k_scale"] = jnp.zeros(sshape, jnp.float32)
         pools["v_scale"] = jnp.zeros(sshape, jnp.float32)
     return pools
 
 
-POOL_AXES = {"k": (None, None, None, "kv_heads", "head_dim"),
-             "v": (None, None, None, "kv_heads", "head_dim"),
-             "k_scale": (None, None, "kv_heads"),
-             "v_scale": (None, None, "kv_heads")}
-
-
 def _check_paged_support(cfg):
     # full-attention GQA only: windowed archs would need window masking in
-    # the paged gather (straightforward; not needed by the examples), and
-    # MLA would page 576-wide latents instead of K/V
+    # the paged kernel (ROADMAP R2), and MLA pages 576-wide latents
+    # instead of K/V (paged_mla_decode_step)
     assert not cfg.use_mla and not cfg.is_encoder_decoder, cfg.name
     assert all(k == "attn" for k in cfg.block_pattern), cfg.block_pattern
 
 
+#: out-of-bounds scatter sentinel: with ``mode="drop"`` a block id this
+#: large drops the update entirely (padded chunk rows write nothing; note
+#: NEGATIVE ids would wrap, so the sentinel must be a large positive)
+_DROP_BLOCK = jnp.int32(2**30)
+
+
+def _scatter_kv(pools, layer, blk, off, k1, v1):
+    """Write a step's K/V rows (B, C, KH, D) into layer ``layer`` at
+    ``[blk, off]`` of the carried pools (``blk == _DROP_BLOCK`` rows write
+    nothing).  int8 pools quantize under the block's running absmax
+    (``kernels.quant.scatter_quantized``)."""
+    pools = dict(pools)
+    if "k_scale" in pools:
+        pools["k"], pools["k_scale"] = scatter_quantized(
+            pools["k"], pools["k_scale"], blk, off, k1, _DROP_BLOCK,
+            layer=layer)
+        pools["v"], pools["v_scale"] = scatter_quantized(
+            pools["v"], pools["v_scale"], blk, off, v1, _DROP_BLOCK,
+            layer=layer)
+        return pools
+    b, c = blk.shape
+    for name, rows in (("k", k1), ("v", v1)):
+        pools[name] = pools[name].at[layer, blk, off].set(
+            rows.reshape(b, c, -1).astype(pools[name].dtype), mode="drop")
+    return pools
+
+
+def _run_layers(cfg, params, pools, x, positions, blk, off, attend):
+    """The layer stack over the paged pools, as one scan over layer groups.
+
+    x (B, C, d); positions/blk/off (B, C).  ``attend(q, pools, layer)``
+    maps the grouped queries (B, C, KH, G, D) to attention outputs of the
+    same shape, reading the pools AFTER this layer's scatter (so a chunk
+    sees its own earlier tokens).  Returns (x, pools).
+    """
+    b, c = positions.shape
+    kh, hd, h = cfg.n_kv_heads, cfg.resolved_head_dim, cfg.n_heads
+    n_pat = len(cfg.block_pattern)
+
+    def group(carry, xs):
+        x, pools = carry
+        gp, g_i = xs
+        for j, kind in enumerate(cfg.block_pattern):
+            bp = gp[f"b{j}_{kind}"]
+            layer = g_i * n_pat + j
+            hn = apply_norm(cfg, bp["norm_mix"], x)
+            q, k1, v1 = _qkv(cfg, bp["mix"], hn, positions)
+            pools = _scatter_kv(pools, layer, blk, off, k1, v1)
+            out = attend(q.reshape(b, c, kh, h // kh, hd), pools, layer)
+            out = out.reshape(b, c, h * hd).astype(x.dtype)
+            x = x + matmul(out, bp["mix"]["wo"])
+            if transformer._has_mlp(cfg):
+                hn = apply_norm(cfg, bp["norm_mlp"], x)
+                ff = moe_mod.apply_moe(cfg, bp["mlp"], hn) if cfg.is_moe \
+                    else apply_mlp(cfg, bp["mlp"], hn)
+                x = x + ff
+        return (x, pools), None
+
+    (x, pools), _ = jax.lax.scan(
+        group, (x, pools), (params["groups"], jnp.arange(cfg.n_groups)))
+    return x, pools
+
+
 def paged_decode_step(cfg, params, pools, tables, lengths, tokens, positions,
-                      *, use_kernel: bool = False):
+                      *, use_kernel: bool | None = None):
     """One token for a batch of requests against the paged pool.
 
     tables (B, nblk) i32; lengths (B,) i32 (INCLUDING the new token);
     tokens (B,) i32; positions (B,) i32 (= lengths - 1).
-    Returns (logits (B, V) f32, updated pools).
+    Returns (logits (B, V) f32, updated pools).  ``use_kernel=None``
+    selects attention by platform (``kernels/platform.py``).
 
     int8 pools (``init_pools(kv_dtype="int8")`` — ``k_scale``/``v_scale``
     present): the scatter quantizes the new token under the block's
     running absmax (``kernels.quant.scatter_quantized``) and attention
-    dequantizes through the scales; fp pools take the bitwise-unchanged
-    original path.
+    dequantizes through the scales.
     """
     _check_paged_support(cfg)
     b = tokens.shape[0]
     bs = pools["k"].shape[2]
-    quantized = "k_scale" in pools
-    kh, hd, h = cfg.n_kv_heads, cfg.resolved_head_dim, cfg.n_heads
-    g = h // kh
+    hd = cfg.resolved_head_dim
     x = embed_tokens(cfg, params["embed"], tokens[:, None])
     # the pool block and in-block offset receiving this token's K/V
     blk_of_tok = tables[jnp.arange(b), positions // bs]  # (B,)
@@ -115,74 +183,22 @@ def paged_decode_step(cfg, params, pools, tables, lengths, tokens, positions,
     # kernel skips their DMA and FLOPs (padded table widths are ~free)
     num_live = (positions // bs + 1).astype(jnp.int32)  # (B,)
 
-    def layer_fn(x, xs):
-        bp, k_pool, v_pool, k_sc, v_sc = xs  # this layer's pools (+scales)
-        hn = apply_norm(cfg, bp["norm_mix"], x)
-        q, k1, v1 = _qkv(cfg, bp["mix"], hn, positions[:, None])
-        # scatter the new K/V into the paged pool
-        if quantized:
-            k_pool, k_sc = scatter_quantized(
-                k_pool, k_sc, blk_of_tok[:, None], off[:, None], k1,
-                _DROP_BLOCK)
-            v_pool, v_sc = scatter_quantized(
-                v_pool, v_sc, blk_of_tok[:, None], off[:, None], v1,
-                _DROP_BLOCK)
-        else:
-            k_pool = k_pool.at[blk_of_tok, off].set(k1[:, 0])
-            v_pool = v_pool.at[blk_of_tok, off].set(v1[:, 0])
-        # (B, 1, KH*G*D) projection -> grouped (B, KH, G, D) query layout
-        qg = q.reshape(b, kh, g, hd)
-        out = paged_decode_attention(qg, k_pool, v_pool, tables, lengths,
-                                     num_live, k_sc, v_sc,
-                                     scale=1.0 / math.sqrt(hd),
-                                     use_kernel=use_kernel)
-        out = out.reshape(b, 1, h * hd).astype(x.dtype)
-        x = x + matmul(out, bp["mix"]["wo"])
-        if transformer._has_mlp(cfg):
-            hn = apply_norm(cfg, bp["norm_mlp"], x)
-            ff = moe_mod.apply_moe(cfg, bp["mlp"], hn) if cfg.is_moe \
-                else apply_mlp(cfg, bp["mlp"], hn)
-            x = x + ff
-        return x, (k_pool, v_pool, k_sc, v_sc)
+    def attend(q, pools, layer):
+        return paged_decode_attention(
+            q[:, 0], pools["k"], pools["v"], tables, lengths, num_live,
+            pools.get("k_scale"), pools.get("v_scale"), layer=layer,
+            scale=1.0 / math.sqrt(hd), use_kernel=use_kernel)[:, None]
 
-    # flatten the group structure: layer l = (group g, pattern j)
-    n_pat = len(cfg.block_pattern)
-
-    def layer_param(l):
-        g_i, j = divmod(l, n_pat)
-        kind = cfg.block_pattern[j]
-        return jax.tree.map(lambda a: a[g_i],
-                            params["groups"][f"b{j}_{kind}"])
-
-    n_layers = cfg.n_groups * n_pat
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for l in range(n_layers):
-        x, (kp, vp, ks, vs) = layer_fn(
-            x, (layer_param(l), pools["k"][l], pools["v"][l],
-                pools["k_scale"][l] if quantized else None,
-                pools["v_scale"][l] if quantized else None))
-        new_k.append(kp)
-        new_v.append(vp)
-        new_ks.append(ks)
-        new_vs.append(vs)
-    pools = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if quantized:
-        pools["k_scale"] = jnp.stack(new_ks)
-        pools["v_scale"] = jnp.stack(new_vs)
+    x, pools = _run_layers(cfg, params, pools, x, positions[:, None],
+                           blk_of_tok[:, None], off[:, None], attend)
     x = apply_norm(cfg, params["final_norm"], x)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
     logits = unembed(cfg, head, x)[:, 0]
     return logits, pools
 
 
-#: out-of-bounds scatter sentinel: with ``mode="drop"`` a block id this
-#: large drops the update entirely (padded chunk rows write nothing; note
-#: NEGATIVE ids would wrap, so the sentinel must be a large positive)
-_DROP_BLOCK = jnp.int32(2**30)
-
-
 def paged_prefill_chunk(cfg, params, pools, tables, tokens, positions,
-                        chunk_lens=None, *, use_kernel: bool = False):
+                        chunk_lens=None, *, use_kernel: bool | None = None):
     """Run a C-token prompt CHUNK against already-materialized pages.
 
     The chunked-prefill device step: the chunk's K/V rows scatter into the
@@ -208,10 +224,8 @@ def paged_prefill_chunk(cfg, params, pools, tables, tokens, positions,
     _check_paged_support(cfg)
     b, c = tokens.shape
     bs = pools["k"].shape[2]
-    quantized = "k_scale" in pools
     nblk = tables.shape[1]
-    kh, hd, h = cfg.n_kv_heads, cfg.resolved_head_dim, cfg.n_heads
-    g = h // kh
+    hd = cfg.resolved_head_dim
     if chunk_lens is None:
         chunk_lens = jnp.full((b,), c, jnp.int32)
     valid = jnp.arange(c)[None, :] < chunk_lens[:, None]  # (B, C)
@@ -227,48 +241,14 @@ def paged_prefill_chunk(cfg, params, pools, tables, tokens, positions,
     num_live = (last_pos // bs + 1).astype(jnp.int32)  # (B,)
     x = embed_tokens(cfg, params["embed"], tokens)
 
-    n_pat = len(cfg.block_pattern)
-    n_layers = cfg.n_groups * n_pat
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for l in range(n_layers):
-        g_i, j = divmod(l, n_pat)
-        kind = cfg.block_pattern[j]
-        bp = jax.tree.map(lambda a: a[g_i], params["groups"][f"b{j}_{kind}"])
-        hn = apply_norm(cfg, bp["norm_mix"], x)
-        q, k1, v1 = _qkv(cfg, bp["mix"], hn, positions)
-        # scatter the chunk's K/V into the paged pool FIRST, so the
-        # attention below sees intra-chunk keys through the same tables
-        k_sc = v_sc = None
-        if quantized:
-            k_pool, k_sc = scatter_quantized(
-                pools["k"][l], pools["k_scale"][l], blk, off, k1,
-                _DROP_BLOCK)
-            v_pool, v_sc = scatter_quantized(
-                pools["v"][l], pools["v_scale"][l], blk, off, v1,
-                _DROP_BLOCK)
-        else:
-            k_pool = pools["k"][l].at[blk, off].set(k1, mode="drop")
-            v_pool = pools["v"][l].at[blk, off].set(v1, mode="drop")
-        qg = q.reshape(b, c, kh, g, hd)
-        out = paged_chunk_attention(qg, k_pool, v_pool, tables, positions,
-                                    num_live, k_sc, v_sc,
-                                    scale=1.0 / math.sqrt(hd),
-                                    use_kernel=use_kernel)
-        out = out.reshape(b, c, h * hd).astype(x.dtype)
-        x = x + matmul(out, bp["mix"]["wo"])
-        if transformer._has_mlp(cfg):
-            hn = apply_norm(cfg, bp["norm_mlp"], x)
-            ff = moe_mod.apply_moe(cfg, bp["mlp"], hn) if cfg.is_moe \
-                else apply_mlp(cfg, bp["mlp"], hn)
-            x = x + ff
-        new_k.append(k_pool)
-        new_v.append(v_pool)
-        new_ks.append(k_sc)
-        new_vs.append(v_sc)
-    pools = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if quantized:
-        pools["k_scale"] = jnp.stack(new_ks)
-        pools["v_scale"] = jnp.stack(new_vs)
+    def attend(q, pools, layer):
+        return paged_chunk_attention(
+            q, pools["k"], pools["v"], tables, positions, num_live,
+            pools.get("k_scale"), pools.get("v_scale"), layer=layer,
+            scale=1.0 / math.sqrt(hd), use_kernel=use_kernel)
+
+    x, pools = _run_layers(cfg, params, pools, x, positions, blk, off,
+                           attend)
     # unembed ONLY each row's last valid token — the chunk that consumes
     # the final prompt token yields the first generated token from it
     last = x[jnp.arange(b), chunk_lens - 1][:, None]  # (B, 1, d)

@@ -14,27 +14,16 @@ shard_map building blocks:
 * ``rs_matmul`` — matmul with reduce-scattered output, same decomposition
   in reverse.
 
-These run under ``jax.experimental.shard_map`` with the model axis explicit.
+These run under ``jax.shard_map`` with the model axis explicit.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 
 __all__ = ["ag_matmul", "rs_matmul", "shard_map"]
-
-
-def _axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size appeared after 0.4.x; psum(1) is the portable form."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def ag_matmul(x_shard: jax.Array, w_shard: jax.Array, axis_name: str
@@ -47,7 +36,7 @@ def ag_matmul(x_shard: jax.Array, w_shard: jax.Array, axis_name: str
     but computed as k chunk-matmuls pipelined with k-1 collective_permutes
     so the ICI transfer of chunk i+1 hides under the matmul of chunk i.
     """
-    k = _axis_size(axis_name)
+    k = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % k) for i in range(k)]
 
@@ -55,9 +44,8 @@ def ag_matmul(x_shard: jax.Array, w_shard: jax.Array, axis_name: str
     m = x_shard.shape[0]
     out = jnp.zeros((k * m, w_shard.shape[1]), x_shard.dtype)
     # mark the accumulator as device-varying so the fori_loop carry type
-    # matches after ppermute (jax >= 0.8 varying-manual-axes tracking)
-    if hasattr(jax.lax, "pcast"):
-        out = jax.lax.pcast(out, (axis_name,), to="varying")
+    # matches after ppermute (varying-manual-axes tracking)
+    out = jax.lax.pcast(out, (axis_name,), to="varying")
 
     def body(i, carry):
         out, chunk = carry
@@ -85,7 +73,7 @@ def rs_matmul(x: jax.Array, w_shard: jax.Array, axis_name: str) -> jax.Array:
     Returns (m/k, p): the reduce_scatter of the full (m, p) partial sums,
     decomposed into k-1 permute+add steps overlapped with chunk matmuls.
     """
-    k = _axis_size(axis_name)
+    k = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = x.shape[0]
     assert m % k == 0, (m, k)

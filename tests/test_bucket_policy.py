@@ -96,11 +96,8 @@ def test_maxlen_compiles_no_more_shapes_than_pow2(smoke_model):
                              era_freq=4, cleanup_freq=4)
         # the jitted steps are lru-shared across engines over one config:
         # clear between policies so counts measure the policy, not order
-        if not engine.clear_compile_caches():
-            pytest.skip("jit cache clearing unavailable")
+        engine.clear_compile_caches()
         before = engine.compile_cache_size()
-        if before is None:
-            pytest.skip("jit cache introspection unavailable")
         tid = engine.pool.register_thread()
         for p, n in PROMPTS:
             engine.submit(p, n)

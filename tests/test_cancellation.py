@@ -280,7 +280,7 @@ def test_cancelled_pages_never_read_poison(dense_model, quiescence_check):
             pools = engine.pools
             dead = np.ones(pools["k"].shape[1], dtype=bool)
             dead[sorted(live)] = False
-            mask = jnp.asarray(dead)[None, :, None, None, None]
+            mask = jnp.asarray(dead)[None, :, None, None]
             engine.pools = {**pools,
                             "k": jnp.where(mask, jnp.nan, pools["k"]),
                             "v": jnp.where(mask, 1e30, pools["v"])}

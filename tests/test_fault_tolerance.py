@@ -276,7 +276,7 @@ def test_reaped_tid_pages_never_read_poison(dense_model, quiescence_check):
     dead_slots = np.ones(pools["k"].shape[1], dtype=bool)
     dead_slots[sorted(live)] = False
     assert dead_slots.any(), "the rewind freed no slots to poison"
-    mask = jnp.asarray(dead_slots)[None, :, None, None, None]
+    mask = jnp.asarray(dead_slots)[None, :, None, None]
     engine.pools = {**pools,
                     "k": jnp.where(mask, jnp.nan, pools["k"]),
                     "v": jnp.where(mask, 1e30, pools["v"])}

@@ -229,6 +229,47 @@ def test_paged_chunk_attention_is_causal():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_chunk_attention_reads_the_named_layer(quantized):
+    """Layer-stacked, lane-packed pools (L, N, bs, KH*D) — the serving
+    layout: with ``layer=l`` the kernel must read exactly layer l (the
+    scalar-prefetched layer index drives the K/V index_map) and match
+    the oracle on that layer's pool."""
+    n_layers, b, c, kh, g, d, bs, nblk = 3, 2, 3, 2, 2, 64, 4, 4
+    ks = jax.random.split(jax.random.key(41), 6)
+    n = b * nblk + 1
+    q = jax.random.normal(ks[0], (b, c, kh, g, d), jnp.float32)
+    shape = (n_layers, n, bs, kh * d)
+    if quantized:
+        k = jax.random.randint(ks[1], shape, -127, 128, jnp.int8)
+        v = jax.random.randint(ks[2], shape, -127, 128, jnp.int8)
+        ksc = jax.random.uniform(ks[4], (n_layers, n, kh), jnp.float32,
+                                 0.005, 0.05)
+        vsc = jax.random.uniform(ks[5], (n_layers, n, kh), jnp.float32,
+                                 0.005, 0.05)
+    else:
+        k = jax.random.normal(ks[1], shape, jnp.float32)
+        v = jax.random.normal(ks[2], shape, jnp.float32)
+        ksc = vsc = None
+    tables = jax.random.permutation(ks[3], n)[: b * nblk].reshape(
+        b, nblk).astype(jnp.int32)
+    qpos = (nblk * bs - c + jnp.arange(c, dtype=jnp.int32))[None, :].repeat(
+        b, axis=0)
+    for layer in range(n_layers):
+        got = paged_attention_chunk(q, k, v, tables, qpos, None, ksc, vsc,
+                                    layer=layer, interpret=True)
+        unstacked = (k[layer].reshape(n, bs, kh, d),
+                     v[layer].reshape(n, bs, kh, d))
+        if quantized:
+            want = ref.paged_attention_chunk_int8_ref(
+                q, *unstacked, ksc[layer], vsc[layer], tables, qpos)
+        else:
+            want = ref.paged_attention_chunk_ref(q, *unstacked, tables, qpos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5,
+                                   err_msg=f"{layer=}")
+
+
 # ================================================ length-bounded grid walks
 @pytest.mark.parametrize("b,c,kh,g,d,bs,nblk", [
     (3, 4, 2, 2, 64, 8, 5),     # ragged contexts mid-prompt
