@@ -53,18 +53,30 @@ most one worker at a time (``Request.inflight``); eviction never targets a
 request whose step is in flight.  Stats are kept per worker — each worker
 increments only its own dict (single-writer, no lock, no lost updates) —
 and merged at aggregation time by the ``stats`` property.
+
+Instrumentation: the serving loop names its own phases as
+``jax.profiler.TraceAnnotation`` spans, so a profiler trace of a running
+server shows them on each worker thread beside the device's planes —
+``serve.tick``, ``serve.lock_wait``, ``serve.complete`` and
+``serve.reclaim`` here; ``serve.dispatch``, ``serve.device_wait`` and
+``serve.idle`` in the engine.  With no profiler running a span costs about
+a microsecond.  The same phases accumulate as integer nanoseconds
+(``time.perf_counter_ns``) in the worker's stats dict, next to the row and
+block counts of every step (docs/serving.md, "Reading a running server").
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .block_pool import PoolExhausted
 from .block_table import BlockTableRef
@@ -78,7 +90,20 @@ STAT_KEYS = ("admitted", "completed", "evictions", "batch_evictions",
              "prefix_hits", "prefix_hit_tokens", "prefix_evictions",
              "cancelled", "cancelled_tokens", "cancelled_blocks",
              "failed", "failed_tokens",
-             "crash_requeues", "crash_wasted_tokens")
+             "crash_requeues", "crash_wasted_tokens",
+             # rows and KV blocks per step: decode rows carried, decode rows
+             # passed over while another worker's step held them, rows sent
+             # to the device after padding, and the blocks the attention
+             # kernel's grid walks (dispatched rows x table width) against
+             # those holding context (the real rows' live blocks)
+             "decode_rows", "decode_rows_held", "rows_dispatched",
+             "kv_blocks_live", "kv_blocks_walked",
+             # time per phase (ns): the whole tick, waiting for the
+             # scheduler lock (tick and complete), host padding + enqueue,
+             # the blocking read of the step's result, the whole complete,
+             # and every reclamation pass on the serving path
+             "tick_ns", "lock_wait_ns", "dispatch_ns", "device_wait_ns",
+             "complete_ns", "reclaim_ns")
 
 #: pseudo worker id for stats written by non-worker threads (the serving
 #: edge calling ``cancel``); writes happen under the scheduler lock, so
@@ -263,7 +288,9 @@ class Scheduler:
         # per-worker stats: tid -> dict, each written by its owner only
         self._worker_stats: Dict[int, Dict[str, int]] = {}
 
-    def _wstats(self, tid: int) -> Dict[str, int]:
+    def worker_stats(self, tid: int) -> Dict[str, int]:
+        """Worker ``tid``'s own stats dict: only code running on that
+        worker's thread writes it (the engine adds its step counts here)."""
         st = self._worker_stats.get(tid)
         if st is None:
             # dict.setdefault is atomic under the GIL; first writer wins
@@ -280,6 +307,36 @@ class Scheduler:
             for k in STAT_KEYS:
                 merged[k] += st[k]
         return merged
+
+    @contextlib.contextmanager
+    def _locked(self, stats: Dict[str, int]):
+        """Hold the scheduler lock; the wait to take it is a
+        ``serve.lock_wait`` span and adds to ``lock_wait_ns``."""
+        t0 = time.perf_counter_ns()
+        with TraceAnnotation("serve.lock_wait"):
+            self._lock.acquire()
+        stats["lock_wait_ns"] += time.perf_counter_ns() - t0
+        try:
+            yield
+        finally:
+            self._lock.release()
+
+    def _reclaim(self, stats: Dict[str, int], cleanup, *args, **kw) -> int:
+        """One reclamation pass (``pool.cleanup`` or ``cleanup_all``) as a
+        ``serve.reclaim`` span: what it frees adds to ``reclaimed``, its
+        time to ``reclaim_ns``."""
+        t0 = time.perf_counter_ns()
+        with TraceAnnotation("serve.reclaim"):
+            freed = cleanup(*args, **kw)
+        stats["reclaim_ns"] += time.perf_counter_ns() - t0
+        stats["reclaimed"] += freed
+        return freed
+
+    def reclaim_all(self, tid: int) -> int:
+        """The idle worker's fused cross-thread drain (``cleanup_all``):
+        frees blocks retired by workers that are stalled or done ticking,
+        counted in worker ``tid``'s stats."""
+        return self._reclaim(self.worker_stats(tid), self.pool.cleanup_all)
 
     # --------------------------------------------------------------- intake
     @property
@@ -344,7 +401,8 @@ class Scheduler:
                         self.queues[req.shard][req.slo].remove(req)
                     except ValueError:  # pragma: no cover - defensive
                         pass  # not queued after all; the sweep finalizes
-                self._finalize_cancelled(req, None, self._wstats(EDGE_TID))
+                self._finalize_cancelled(req, None,
+                                         self.worker_stats(EDGE_TID))
             self._work.notify_all()  # wake a worker to sweep/finish it
             return True
 
@@ -360,9 +418,13 @@ class Scheduler:
         With a sharded pool each plan draws from ONE shard (the plan's
         device step then touches only that shard's KV-pool chain, so steps
         on different shards execute concurrently).  Shards are tried
-        starting from the caller's affinity (``tid % n_shards``).
+        starting from the caller's affinity (``tid % n_shards``).  The
+        call is a ``serve.tick`` span and adds to ``tick_ns``.
         """
-        with self._lock:
+        stats = self.worker_stats(tid)
+        t0 = time.perf_counter_ns()
+        plan = None
+        with TraceAnnotation("serve.tick"), self._locked(stats):
             # drop cancelled requests FIRST: rows excluded from this (and
             # every later) plan, their pages released through the normal
             # refcount/era path before any new allocation competes for them
@@ -370,8 +432,9 @@ class Scheduler:
             for k in range(self.n_shards):
                 plan = self._tick_locked(tid, (tid + k) % self.n_shards)
                 if plan is not None:
-                    return plan
-            return None
+                    break
+        stats["tick_ns"] += time.perf_counter_ns() - t0
+        return plan
 
     def _sweep_cancelled(self, tid: int) -> None:
         """Finalize every cancelled active request with no step outstanding
@@ -379,7 +442,7 @@ class Scheduler:
         ``complete`` — the era reservation of the dispatched step is still
         live, and the completion path finalizes them right after releasing
         it."""
-        stats = self._wstats(tid)
+        stats = self.worker_stats(tid)
         for req in [r for r in self.active
                     if r.cancelled and not r.inflight]:
             self._finalize_cancelled(req, tid, stats)
@@ -459,7 +522,7 @@ class Scheduler:
         cleared separately by ``reap_thread`` (caller runs reap FIRST so
         the evictions' cleanup can free the released pages immediately).
         """
-        stats = self._wstats(tid)
+        stats = self.worker_stats(tid)
         with self._lock:
             for req in plan.requests:
                 if not req.inflight:
@@ -476,7 +539,7 @@ class Scheduler:
             self._work.notify_all()
 
     def _tick_locked(self, tid: int, shard: int) -> Optional[StepPlan]:
-        stats = self._wstats(tid)
+        stats = self.worker_stats(tid)
         deadline = time.monotonic() + self.deadline_ms / 1e3
         self._admit(tid, shard, deadline, stats)
         if not self.active:
@@ -532,8 +595,8 @@ class Scheduler:
         chunk from the remainder — one plan, one dispatch, one reservation.
         """
         budget = self.token_budget
-        runnable = self._gather_decode(tid, shard, deadline, stats,
-                                       cap=min(self.max_batch, budget))
+        runnable, held = self._gather_decode(tid, shard, deadline, stats,
+                                             cap=min(self.max_batch, budget))
         budget -= len(runnable)
         pre, n = None, 0
         if budget > 0:
@@ -551,6 +614,7 @@ class Scheduler:
                     break
         if not runnable and pre is None:
             return None
+        stats["decode_rows_held"] += held
         slot = self._slots.popleft()
         # ORDER MATTERS (Lemma 4 discipline): publish the era reservation
         # FIRST, then snapshot tables — everything read after the publish
@@ -580,16 +644,18 @@ class Scheduler:
                 return self._build_prefill_plan(req, n, slot, shard, stats)
             # no pages for even one token of this request: try the next
             # candidate (or fall through to a decode batch)
-        runnable = self._gather_decode(tid, shard, deadline, stats,
-                                       cap=self.max_batch)
+        runnable, held = self._gather_decode(tid, shard, deadline, stats,
+                                             cap=self.max_batch)
         if not runnable:
             return None
+        stats["decode_rows_held"] += held
         slot = self._slots.popleft()
         self.pool.protect_step(slot, tid, shard=shard)
         return self._build_decode_plan(runnable, slot, shard, stats)
 
     def _gather_decode(self, tid: int, shard: int, deadline: float,
-                       stats: Dict[str, int], cap: int) -> List[Request]:
+                       stats: Dict[str, int],
+                       cap: int) -> Tuple[List[Request], int]:
         """Collect up to ``cap`` decode-phase rows, allocating a fresh
         block where a request crosses a block boundary.  Priority is
         admission order (FCFS): under pool pressure the shedding ladder
@@ -597,7 +663,10 @@ class Scheduler:
         LIFO), so the oldest request makes monotonic progress — no
         eviction livelock.  Requests whose previous step is still in
         flight (another worker's) are skipped; they rejoin once that
-        worker completes them.
+        worker completes them.  Returns the rows and the number of
+        decode-phase rows so skipped (the planners count them as
+        ``decode_rows_held`` when the tick yields a plan: an idle tick
+        re-finding the same held rows is no step that left them out).
 
         The planning deadline covers the WHOLE phase: once at least one
         row is gathered, crossing the deadline cuts the batch (run what we
@@ -607,12 +676,17 @@ class Scheduler:
         progress (one ladder step) so a zero deadline cannot livelock.
         """
         runnable: List[Request] = []
+        held = 0
         for req in list(self.active):
             if req.state != "active" or req.inflight or req.shard != shard \
                     or req.phase != "decode":
-                continue  # evicted earlier in this loop, being stepped,
-                # pinned to a different shard's device chain, or still
-                # materializing its prompt (the prefill planner's job)
+                # evicted earlier in this loop, being stepped, pinned to a
+                # different shard's device chain, or still materializing
+                # its prompt (the prefill planner's job)
+                if req.inflight and req.state == "active" \
+                        and req.shard == shard and req.phase == "decode":
+                    held += 1  # another worker's step holds this row
+                continue
             if len(runnable) >= cap:
                 break
             if runnable and time.monotonic() > deadline:
@@ -642,7 +716,7 @@ class Scheduler:
                 if not got:
                     continue
             runnable.append(req)
-        return runnable
+        return runnable, held
 
     def _evict_cache_entry(self, tid: int, shard: int,
                            stats: Dict[str, int]) -> bool:
@@ -834,13 +908,23 @@ class Scheduler:
         finalizes to the terminal ``failed`` state after ``release_step``,
         through the same post-reservation ordering as a cancelled
         in-flight row.
+
+        The call is a ``serve.complete`` span and adds to ``complete_ns``.
         """
-        stats = self._wstats(tid)
+        stats = self.worker_stats(tid)
+        t0 = time.perf_counter_ns()
+        with TraceAnnotation("serve.complete"):
+            self._complete_step(plan, sampled, tid, stats, failed_rows)
+        stats["complete_ns"] += time.perf_counter_ns() - t0
+
+    def _complete_step(self, plan: StepPlan, sampled: np.ndarray, tid: int,
+                       stats: Dict[str, int],
+                       failed_rows: Optional[List[bool]]) -> None:
         failed_rids = set()
         if failed_rows is not None:
             failed_rids = {req.rid for req, bad
                            in zip(plan.requests, failed_rows) if bad}
-        with self._lock:
+        with self._locked(stats):
             if failed_rids:
                 for req in plan.requests:
                     if req.rid in failed_rids:
@@ -887,7 +971,7 @@ class Scheduler:
         # sharding every retire from this complete — blocks AND table
         # versions, both pinned to the request's shard — landed in
         # plan.shard, so one shard's drain covers them.
-        stats["reclaimed"] += self.pool.cleanup(tid, shard=plan.shard)
+        self._reclaim(stats, self.pool.cleanup, tid, shard=plan.shard)
 
     def _complete_decode(self, req: Request, tok: int, tid: int,
                          stats: Dict[str, int]) -> None:
@@ -1013,11 +1097,11 @@ class Scheduler:
             # brand-new arrivals would balloon it unfairly — a preempted
             # request re-admits before anything submitted after it
             self.queues[req.shard][req.slo].appendleft(req)
-        stats = self._wstats(tid)
+        stats = self.worker_stats(tid)
         stats["evictions"] += 1
         if req.slo == "batch":
             stats["batch_evictions"] += 1
         # scoped to the pressured shard: _evict runs under the scheduler
         # lock, so a full cross-shard fan-out here would serialize every
         # other worker's planning behind reclamation
-        stats["reclaimed"] += self.pool.cleanup(tid, shard=req.shard)
+        self._reclaim(stats, self.pool.cleanup, tid, shard=req.shard)

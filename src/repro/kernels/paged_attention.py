@@ -43,7 +43,8 @@ prefill); single-token decode is the C == 1 specialization:
   clamp dead slots ``j >= num_live_blocks[b]`` to the request's last live
   block (a repeated block index, so the pipeline elides the HBM copy),
   and the kernel body skips the score/accumulate math for them — padded
-  table slots cost neither DMA nor FLOPs.  Finalization happens on the
+  table slots cost neither DMA nor FLOPs, only their grid steps (the
+  grid stays ``(rows, W)``).  Finalization happens on the
   last grid step regardless, reading the accumulator state a short row
   stopped updating at its own boundary;
 * QUANTIZED pools (``kv_dtype="int8"``): the layer's per-(block, kv-head)

@@ -33,11 +33,14 @@ Shape buckets (``bucket_policy``): every step pads its block table to a
 width bucket so XLA compiles once per bucket.  The default ``"maxlen"``
 buckets on the batch's FINAL width (known at admission from prompt +
 max_new_tokens): a request stays in one bucket for its whole lifetime, so
-growing contexts never recompile mid-decode.  Padding is cheap because
-the paged kernels are LENGTH-BOUNDED: a per-request ``num_live_blocks``
-vector (derived in ``paged_model`` from lengths/positions) stops the
-kernel's table walk at the last live slot — dead slots cost neither DMA
-nor FLOPs.  ``"pow2"`` keeps the legacy current-width ladder.
+growing contexts never recompile mid-decode.  The paged kernels are
+LENGTH-BOUNDED: a per-request ``num_live_blocks`` vector (derived in
+``paged_model`` from lengths/positions) stops the kernel's table walk at
+the last live slot — a dead slot skips its DMA and FLOPs, but still
+costs the kernel one grid step, as does every padded row.  The worker's
+``kv_blocks_walked`` (dispatched rows x table width) against
+``kv_blocks_live`` counts how many.  ``"pow2"`` keeps the legacy
+current-width ladder.
 
 Prefix caching (``prefix_caching=True``, the default): prompts sharing a
 block-aligned token prefix alias the same pool pages via the refcounted
@@ -74,6 +77,7 @@ number of clock ticks), replacing the old fixed-64-round loop.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -89,8 +93,9 @@ from repro.kernels import platform
 from repro.kernels.paged_attention import check_int8_scales_fit
 from repro.models.common import ArchConfig
 
-from .paged_model import (KV_DTYPES, init_pools, paged_decode_step,
-                          paged_prefill_chunk, pool_shape)
+from .paged_model import (KV_DTYPES, init_pools, live_blocks,
+                          paged_decode_step, paged_prefill_chunk,
+                          pool_shape)
 
 __all__ = ["ServeEngine", "device_pool_blocks", "pool_block_bytes",
            "pool_blocks_that_fit"]
@@ -244,8 +249,8 @@ class ServeEngine:
         # width) so XLA compiles once per bucket instead of once per
         # (B, nblk) — without it the serve loop is recompile-bound
         # (hundreds of ms per shape) and multi-worker pipelining has
-        # nothing to overlap.  Width policy (the padded slots are ~free:
-        # the length-bounded kernel skips their DMA and FLOPs):
+        # nothing to overlap.  Width policy (the length-bounded kernel
+        # skips a padded slot's DMA and FLOPs, not its grid step):
         #   "maxlen" (default) — pow2 of the batch's FINAL table width,
         #     known at admission (prompt + max_new_tokens), ratcheted by a
         #     per-shard high-water mark so the width never NARROWS either
@@ -325,6 +330,9 @@ class ServeEngine:
         # copying every page each token
         self._decode = _jit_decode(cfg, self.use_kernel)
         self._prefill = _jit_prefill(cfg, self.use_kernel)
+        # the padded (rows, width) of the step this thread dispatched
+        # last: ``_dispatching`` sets it, ``execute_plan`` counts from it
+        self._dispatched = threading.local()
 
     # ------------------------------------------- compile-cache introspection
     # the jitted steps are lru-shared across engines over one config; the
@@ -410,15 +418,39 @@ class ServeEngine:
     def execute_plan(self, plan, tid: int) -> np.ndarray:
         """Dispatch one typed plan to the device and account the result.
 
-        Benchmarks call this directly after timing ``sched.tick`` — the
-        planner and the device step are separately measurable.
+        Callable on its own after ``sched.tick``, so the planner and the
+        device step can be driven separately.  The step shows on a
+        profiler trace as ``serve.dispatch`` (host padding and the
+        enqueue, with the plan's kind, rows, padded rows and table width),
+        ``serve.device_wait`` (the blocking read of the sampled ids) and
+        ``serve.complete``; worker ``tid``'s stats gain the step's rows,
+        KV blocks and phase times (``Scheduler`` ``STAT_KEYS``).
         """
+        stats = self.sched.worker_stats(tid)
+        b = len(plan.requests)
+        t0 = time.perf_counter_ns()
         if plan.kind == "prefill":
-            sampled = self._dispatch_prefill(plan)
+            out = self._dispatch_prefill(plan)
         elif plan.kind == "mixed":
-            sampled = self._dispatch_mixed(plan)
+            out = self._dispatch_mixed(plan)
         else:
-            sampled = self._dispatch_decode(plan)
+            out = self._dispatch_decode(plan)
+        t1 = time.perf_counter_ns()
+        # block on the result OUTSIDE the device lock: other workers plan,
+        # dispatch and run OTHER shards' chains while this one waits
+        with jax.profiler.TraceAnnotation("serve.device_wait"):
+            sampled = np.asarray(out)[:b]
+        stats["device_wait_ns"] += time.perf_counter_ns() - t1
+        stats["dispatch_ns"] += t1 - t0
+        rows, width = self._dispatched.shape
+        stats["decode_rows"] += (plan.n_decode if plan.kind == "mixed"
+                                 else b if plan.kind == "decode" else 0)
+        stats["rows_dispatched"] += rows
+        stats["kv_blocks_walked"] += rows * width
+        # every real row's last query sits at length - 1: the position
+        # ``paged_model`` derives the row's num_live_blocks from
+        stats["kv_blocks_live"] += int(live_blocks(
+            np.asarray(plan.lengths) - 1, self.block_size).sum())
         faults = self.faults
         if faults is not None:
             row = faults.poison_row(len(plan.requests))
@@ -456,16 +488,32 @@ class ServeEngine:
             # ratchet DECODE (and mixed-batch) widths: batch membership
             # changes (a wide request completing) must never shrink the
             # width into a never-compiled shape mid-decode — padding wider
-            # is ~free (the bounded kernel skips dead slots), recompiling
-            # is not.  Pure prefill needs no ratchet: B == 1, so its width
-            # is the one request's own final — stable across all chunks.
+            # costs grid steps (the bounded kernel skips a dead slot's DMA
+            # and FLOPs), recompiling costs a compile.  Pure prefill
+            # needs no ratchet: B == 1, so its width is the one request's
+            # own final — stable across all chunks.
             w = max(w, self._width_hwm[shard])
             self._width_hwm[shard] = w
         return w
 
-    def _bucket_tables(self, plan, rows: int):
+    @contextlib.contextmanager
+    def _dispatching(self, plan, rows: int):
+        """The ``serve.dispatch`` span of one step (host padding and the
+        enqueue).  Yields the table width the step pads to, and records
+        the padded shape ``(rows, width)`` for ``execute_plan``."""
+        nblk = plan.tables.shape[1]
+        width = (self._bucket_width(plan, nblk, plan.shard)
+                 if self.pad_shapes else nblk)
+        self._dispatched.shape = (rows, width)
+        with jax.profiler.TraceAnnotation(
+                "serve.dispatch", kind=plan.kind, rows=len(plan.requests),
+                dispatched=rows, width=width):
+            yield width
+
+    def _bucket_tables(self, plan, rows: int, width: Optional[int] = None):
         """Shard-localize + (optionally) pad a plan's table to its width
-        bucket.  Returns (tables (rows, W) i32, pad_slot)."""
+        bucket (``width``, else the one ``_bucket_width`` picks).  Returns
+        (tables (rows, W) i32, pad_slot)."""
         s = plan.shard
         base = self._shard_bases[s]
         pad_slot = self._shard_sizes[s]  # shard-local scratch slot id
@@ -478,60 +526,62 @@ class ServeEngine:
         if not self.pad_shapes:
             return local, pad_slot
         b, nblk = local.shape
-        w = self._bucket_width(plan, nblk, s)
+        w = self._bucket_width(plan, nblk, s) if width is None else width
         tables = np.full((rows, w), pad_slot, np.int32)
         tables[:b, :] = 0
         tables[:b, :nblk] = local
         return tables, pad_slot
 
-    def _dispatch_decode(self, plan) -> np.ndarray:
+    def _dispatch_decode(self, plan) -> jax.Array:
+        """Pad a decode batch to ``max_batch`` rows and its width bucket
+        and enqueue it; returns the (padded) sampled ids, not waited on."""
         s = plan.shard
         b = plan.tables.shape[0]
         rows = self.max_batch if self.pad_shapes else b
-        tables, _ = self._bucket_tables(plan, rows)
-        lengths, tokens, positions = (plan.lengths, plan.tokens,
-                                      plan.positions)
-        if self.pad_shapes:
-            lengths = np.ones((rows,), np.int32)  # pad rows: 1 scratch token
-            lengths[:b] = plan.lengths
-            tokens = np.zeros((rows,), np.int32)
-            tokens[:b] = plan.tokens
-            positions = np.zeros((rows,), np.int32)
-            positions[:b] = plan.positions
-        with self._device_locks[s]:
-            out, self._shard_pools[s] = self._decode(
-                self.params, self._shard_pools[s],
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(tokens), jnp.asarray(positions))
-        # block on the result OUTSIDE the lock: other workers plan/dispatch
-        # and execute OTHER shards' chains while this one waits
-        return np.asarray(out)[:b]
+        with self._dispatching(plan, rows) as width:
+            tables, _ = self._bucket_tables(plan, rows, width)
+            lengths, tokens, positions = (plan.lengths, plan.tokens,
+                                          plan.positions)
+            if self.pad_shapes:
+                lengths = np.ones((rows,), np.int32)  # pad rows: 1 token
+                lengths[:b] = plan.lengths
+                tokens = np.zeros((rows,), np.int32)
+                tokens[:b] = plan.tokens
+                positions = np.zeros((rows,), np.int32)
+                positions[:b] = plan.positions
+            with self._device_locks[s]:
+                out, self._shard_pools[s] = self._decode(
+                    self.params, self._shard_pools[s],
+                    jnp.asarray(tables), jnp.asarray(lengths),
+                    jnp.asarray(tokens), jnp.asarray(positions))
+        return out
 
-    def _dispatch_prefill(self, plan) -> np.ndarray:
+    def _dispatch_prefill(self, plan) -> jax.Array:
         """One prefill chunk (B == 1): pad the chunk length to its pow2
         bucket next to the table-width buckets (``bucket_policy``), so XLA
         compiles once per (chunk bucket, width bucket) instead of per
-        chunk shape."""
+        chunk shape.  Returns the sampled id, not waited on."""
         s = plan.shard
         n = plan.n_tokens
         ctx = int(plan.lengths[0]) - n  # context BEFORE the chunk
         cb = 1 << max(0, n - 1).bit_length() if self.pad_shapes else n
-        tables, _ = self._bucket_tables(plan, 1)
-        tokens = np.zeros((1, cb), np.int32)
-        tokens[0, :n] = plan.tokens
-        # pad positions clamp to the last valid one: their (discarded)
-        # attention rows stay masked to materialized pages — no NaN risk
-        positions = (ctx + np.minimum(np.arange(cb), n - 1)
-                     ).astype(np.int32)[None, :]
-        chunk_lens = np.array([n], np.int32)
-        with self._device_locks[s]:
-            out, self._shard_pools[s] = self._prefill(
-                self.params, self._shard_pools[s],
-                jnp.asarray(tables), jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(chunk_lens))
-        return np.asarray(out)[:1]
+        with self._dispatching(plan, 1) as width:
+            tables, _ = self._bucket_tables(plan, 1, width)
+            tokens = np.zeros((1, cb), np.int32)
+            tokens[0, :n] = plan.tokens
+            # pad positions clamp to the last valid one: their (discarded)
+            # attention rows stay masked to materialized pages — no NaN risk
+            positions = (ctx + np.minimum(np.arange(cb), n - 1)
+                         ).astype(np.int32)[None, :]
+            chunk_lens = np.array([n], np.int32)
+            with self._device_locks[s]:
+                out, self._shard_pools[s] = self._prefill(
+                    self.params, self._shard_pools[s],
+                    jnp.asarray(tables), jnp.asarray(tokens),
+                    jnp.asarray(positions), jnp.asarray(chunk_lens))
+        return out
 
-    def _dispatch_mixed(self, plan) -> np.ndarray:
+    def _dispatch_mixed(self, plan) -> jax.Array:
         """Decode rows + one prefill chunk row in ONE dispatch of the
         chunked kernel (ragged rows via ``chunk_lens``; decode rows carry
         1 valid token).  Shape buckets: rows pad to ``max_batch + 1`` (a
@@ -540,27 +590,28 @@ class ServeEngine:
         count stays bounded.  Pad rows write their (masked) token to the
         scratch slot; pad columns clamp to each row's last valid position
         so their discarded attention rows stay within materialized pages.
+        Returns the (padded) sampled ids, not waited on.
         """
         s = plan.shard
         b, c = plan.tokens.shape
         rows = (self.max_batch + 1) if self.pad_shapes else b
-        tables, _ = self._bucket_tables(plan, rows)
         cb = 1 << max(0, c - 1).bit_length() if self.pad_shapes else c
-        tokens = np.zeros((rows, cb), np.int32)
-        tokens[:b, :c] = plan.tokens
-        positions = np.zeros((rows, cb), np.int32)
-        positions[:b, :c] = plan.positions
-        if cb > c:
-            positions[:b, c:] = plan.positions[:, c - 1:c]
-        chunk_lens = np.ones((rows,), np.int32)  # pad rows: 1 scratch token
-        chunk_lens[:b] = plan.chunk_lens
-        with self._device_locks[s]:
-            out, self._shard_pools[s] = self._prefill(
-                self.params, self._shard_pools[s],
-                jnp.asarray(tables), jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(chunk_lens))
-        # block on the result OUTSIDE the lock (see _dispatch_decode)
-        return np.asarray(out)[:b]
+        with self._dispatching(plan, rows) as width:
+            tables, _ = self._bucket_tables(plan, rows, width)
+            tokens = np.zeros((rows, cb), np.int32)
+            tokens[:b, :c] = plan.tokens
+            positions = np.zeros((rows, cb), np.int32)
+            positions[:b, :c] = plan.positions
+            if cb > c:
+                positions[:b, c:] = plan.positions[:, c - 1:c]
+            chunk_lens = np.ones((rows,), np.int32)  # pad rows: 1 token
+            chunk_lens[:b] = plan.chunk_lens
+            with self._device_locks[s]:
+                out, self._shard_pools[s] = self._prefill(
+                    self.params, self._shard_pools[s],
+                    jnp.asarray(tables), jnp.asarray(tokens),
+                    jnp.asarray(positions), jnp.asarray(chunk_lens))
+        return out
 
     # ------------------------------------------------------------- drain
     def drain(self, tid: int) -> int:
@@ -639,9 +690,10 @@ class ServeEngine:
             # spin here starves the working threads of the GIL.
             idle += 1
             if idle % 4 == 1:
-                self.pool.cleanup_all()
+                self.sched.reclaim_all(tid)
             else:
-                self.sched.wait_for_work(0.002)
+                with jax.profiler.TraceAnnotation("serve.idle"):
+                    self.sched.wait_for_work(0.002)
         return productive
 
     def run(self, tid: int, max_steps: int = 10_000) -> Dict[str, int]:

@@ -49,6 +49,12 @@ def pool_shape(cfg, n_blocks: int, block_size: int) -> tuple:
             cfg.n_kv_heads * cfg.resolved_head_dim)
 
 
+def live_blocks(last_positions, block_size: int):
+    """Each row's live table slots, ``num_live_blocks``: through the block
+    holding the row's last query position (NumPy or JAX arrays alike)."""
+    return last_positions // block_size + 1
+
+
 def init_pools(cfg, n_blocks: int, block_size: int, kv_dtype=None):
     """One K and one V pool, layer-stacked: ``pool_shape`` above.
 
@@ -180,8 +186,8 @@ def paged_decode_step(cfg, params, pools, tables, lengths, tokens, positions,
     off = positions % bs
     # per-request LIVE table slots: the decode token's own block is the
     # last one holding context, so slots beyond it are dead — the bounded
-    # kernel skips their DMA and FLOPs (padded table widths are ~free)
-    num_live = (positions // bs + 1).astype(jnp.int32)  # (B,)
+    # kernel skips their DMA and FLOPs (each still costs a grid step)
+    num_live = live_blocks(positions, bs).astype(jnp.int32)  # (B,)
 
     def attend(q, pools, layer):
         return paged_decode_attention(
@@ -238,7 +244,7 @@ def paged_prefill_chunk(cfg, params, pools, tables, tokens, positions,
     # walks exactly that many slots (padded rows clamp to the row's last
     # valid position, so they derive the same bound)
     last_pos = positions[jnp.arange(b), jnp.maximum(chunk_lens - 1, 0)]
-    num_live = (last_pos // bs + 1).astype(jnp.int32)  # (B,)
+    num_live = live_blocks(last_pos, bs).astype(jnp.int32)  # (B,)
     x = embed_tokens(cfg, params["embed"], tokens)
 
     def attend(q, pools, layer):
