@@ -42,8 +42,8 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 
 #: Kernel vs reference: the largest |logit difference| may be this share
 #: of the largest |reference logit|.  Both paths run the same bf16 model
-#: and differ only inside attention (an online softmax over 16-token
-#: blocks against one softmax over the whole table, and different f32
+#: and differ only inside attention (an online softmax over groups of
+#: pages against one softmax over the whole table, and different f32
 #: summation orders).  Where the two f32 attention outputs fall on
 #: opposite sides of a bf16 rounding boundary, the cast back to bf16
 #: differs by one ulp (2^-8 relative), and that difference travels
@@ -57,7 +57,7 @@ LOGIT_RTOL = 2e-2
 #: largest |reference output|.  The queries are f32 holding bf16 values,
 #: so neither output is rounded to bf16 and no later layer amplifies
 #: anything: what is left is the kernel's own arithmetic (an online
-#: softmax over 16-token blocks, and matmul passes that may round the
+#: softmax over groups of pages, and matmul passes that may round the
 #: probabilities to bf16, 2^-9 relative each).  2^-7 admits that; a wrong
 #: page, head, position or mask moves the output by a share of order one.
 ATTN_RTOL = 2.0 ** -7

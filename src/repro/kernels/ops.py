@@ -64,9 +64,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
 
     Pools are (N,bs,KH,D), or the lane-packed, layer-stacked
     (L,N,bs,KH*D) with ``layer`` naming the one to read.
-    ``num_live_blocks`` (B,) i32 bounds each request's table walk (dead
-    slots skip their DMA and FLOPs in the kernel path, though each still
-    takes a grid step; the ref masks them).  ``k_scales``/``v_scales`` (N, KH)
+    ``num_live_blocks`` (B,) i32 bounds each request's table walk (the
+    kernel path fetches and folds in only the live pages; the ref masks
+    the rest).  ``k_scales``/``v_scales`` (N, KH)
     f32 (or (L, N, KH)) select the int8 pool mode: the kernel dequantizes
     in-register after the VMEM load; the ref path materializes the
     identical dequant.

@@ -35,12 +35,13 @@ buckets on the batch's FINAL width (known at admission from prompt +
 max_new_tokens): a request stays in one bucket for its whole lifetime, so
 growing contexts never recompile mid-decode.  The paged kernels are
 LENGTH-BOUNDED: a per-request ``num_live_blocks`` vector (derived in
-``paged_model`` from lengths/positions) stops the kernel's table walk at
-the last live slot — a dead slot skips its DMA and FLOPs, but still
-costs the kernel one grid step, as does every padded row.  The worker's
-``kv_blocks_walked`` (dispatched rows x table width) against
-``kv_blocks_live`` counts how many.  ``"pow2"`` keeps the legacy
-current-width ladder.
+``paged_model`` from lengths/positions) ends the kernel's table walk at
+the last live slot — the kernel fetches and folds in only the live
+pages, so a wider bucket costs nothing in the kernel; a padded row still
+fetches its one scratch page.  The worker's ``kv_blocks_walked`` (pages
+fetched per layer: the real rows' ``num_live_blocks`` plus one a pad
+row) against ``kv_blocks_live`` counts how many.  ``"pow2"`` keeps the
+legacy current-width ladder.
 
 Prefix caching (``prefix_caching=True``, the default): prompts sharing a
 block-aligned token prefix alias the same pool pages via the refcounted
@@ -250,7 +251,7 @@ class ServeEngine:
         # (B, nblk) — without it the serve loop is recompile-bound
         # (hundreds of ms per shape) and multi-worker pipelining has
         # nothing to overlap.  Width policy (the length-bounded kernel
-        # skips a padded slot's DMA and FLOPs, not its grid step):
+        # walks only a row's live pages, so a padded slot costs nothing):
         #   "maxlen" (default) — pow2 of the batch's FINAL table width,
         #     known at admission (prompt + max_new_tokens), ratcheted by a
         #     per-shard high-water mark so the width never NARROWS either
@@ -330,8 +331,8 @@ class ServeEngine:
         # copying every page each token
         self._decode = _jit_decode(cfg, self.use_kernel)
         self._prefill = _jit_prefill(cfg, self.use_kernel)
-        # the padded (rows, width) of the step this thread dispatched
-        # last: ``_dispatching`` sets it, ``execute_plan`` counts from it
+        # the padded row count of the step this thread dispatched last:
+        # ``_dispatching`` sets it, ``execute_plan`` counts from it
         self._dispatched = threading.local()
 
     # ------------------------------------------- compile-cache introspection
@@ -442,15 +443,18 @@ class ServeEngine:
             sampled = np.asarray(out)[:b]
         stats["device_wait_ns"] += time.perf_counter_ns() - t1
         stats["dispatch_ns"] += t1 - t0
-        rows, width = self._dispatched.shape
+        rows = self._dispatched.rows
         stats["decode_rows"] += (plan.n_decode if plan.kind == "mixed"
                                  else b if plan.kind == "decode" else 0)
         stats["rows_dispatched"] += rows
-        stats["kv_blocks_walked"] += rows * width
         # every real row's last query sits at length - 1: the position
-        # ``paged_model`` derives the row's num_live_blocks from
-        stats["kv_blocks_live"] += int(live_blocks(
-            np.asarray(plan.lengths) - 1, self.block_size).sum())
+        # ``paged_model`` derives the row's num_live_blocks from, and the
+        # pages the kernel fetches for it in each layer; a pad row fetches
+        # the scratch slot's one page
+        live = int(live_blocks(np.asarray(plan.lengths) - 1,
+                               self.block_size).sum())
+        stats["kv_blocks_live"] += live
+        stats["kv_blocks_walked"] += live + rows - b
         faults = self.faults
         if faults is not None:
             row = faults.poison_row(len(plan.requests))
@@ -488,8 +492,8 @@ class ServeEngine:
             # ratchet DECODE (and mixed-batch) widths: batch membership
             # changes (a wide request completing) must never shrink the
             # width into a never-compiled shape mid-decode — padding wider
-            # costs grid steps (the bounded kernel skips a dead slot's DMA
-            # and FLOPs), recompiling costs a compile.  Pure prefill
+            # costs the kernel nothing (it walks only live pages) and the
+            # host a wider table, recompiling costs a compile.  Pure prefill
             # needs no ratchet: B == 1, so its width is the one request's
             # own final — stable across all chunks.
             w = max(w, self._width_hwm[shard])
@@ -500,11 +504,11 @@ class ServeEngine:
     def _dispatching(self, plan, rows: int):
         """The ``serve.dispatch`` span of one step (host padding and the
         enqueue).  Yields the table width the step pads to, and records
-        the padded shape ``(rows, width)`` for ``execute_plan``."""
+        the padded row count for ``execute_plan``."""
         nblk = plan.tables.shape[1]
         width = (self._bucket_width(plan, nblk, plan.shard)
                  if self.pad_shapes else nblk)
-        self._dispatched.shape = (rows, width)
+        self._dispatched.rows = rows
         with jax.profiler.TraceAnnotation(
                 "serve.dispatch", kind=plan.kind, rows=len(plan.requests),
                 dispatched=rows, width=width):

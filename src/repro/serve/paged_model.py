@@ -185,8 +185,8 @@ def paged_decode_step(cfg, params, pools, tables, lengths, tokens, positions,
     blk_of_tok = tables[jnp.arange(b), positions // bs]  # (B,)
     off = positions % bs
     # per-request LIVE table slots: the decode token's own block is the
-    # last one holding context, so slots beyond it are dead — the bounded
-    # kernel skips their DMA and FLOPs (each still costs a grid step)
+    # last one holding context, so slots beyond it are dead — the kernel
+    # fetches and folds in only the live pages
     num_live = live_blocks(positions, bs).astype(jnp.int32)  # (B,)
 
     def attend(q, pools, layer):

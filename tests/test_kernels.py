@@ -19,7 +19,8 @@ except ModuleNotFoundError:
 
 from repro.kernels import ref
 from repro.kernels.era_scan import INF_ERA32, era_scan
-from repro.kernels.paged_attention import (paged_attention,
+from repro.kernels.paged_attention import (heads_per_block, pages_per_group,
+                                           paged_attention,
                                            paged_attention_chunk)
 
 jax.config.update("jax_enable_x64", False)
@@ -233,7 +234,7 @@ def test_paged_chunk_attention_is_causal():
 def test_paged_chunk_attention_reads_the_named_layer(quantized):
     """Layer-stacked, lane-packed pools (L, N, bs, KH*D) — the serving
     layout: with ``layer=l`` the kernel must read exactly layer l (the
-    scalar-prefetched layer index drives the K/V index_map) and match
+    scalar-prefetched layer index names the pages it copies) and match
     the oracle on that layer's pool."""
     n_layers, b, c, kh, g, d, bs, nblk = 3, 2, 3, 2, 2, 64, 4, 4
     ks = jax.random.split(jax.random.key(41), 6)
@@ -270,17 +271,21 @@ def test_paged_chunk_attention_reads_the_named_layer(quantized):
                                    err_msg=f"{layer=}")
 
 
-# ================================================ length-bounded grid walks
+# ================================================ length-bounded walks
 @pytest.mark.parametrize("b,c,kh,g,d,bs,nblk", [
     (3, 4, 2, 2, 64, 8, 5),     # ragged contexts mid-prompt
     (2, 1, 1, 4, 64, 16, 4),    # C == 1 (decode-as-chunk)
     (1, 8, 2, 1, 128, 4, 7),    # chunk wider than a block
+    (3, 2, 2, 1, 64, 32, 11),   # page groups of 4: full groups + a tail
+    (2, 1, 32, 1, 80, 16, 11),  # stablelm-3b's MHA packing, groups of 8
+    (2, 3, 2, 12, 128, 16, 11),  # starcoder2-3b's GQA packing (G = 12)
 ])
 def test_bounded_grid_bitwise_equals_unbounded(b, c, kh, g, d, bs, nblk):
-    """The dead iterations the ``num_live_blocks`` bound skips were exact
-    no-ops of the flash update (every position causally masked: p = 0,
-    corr = exp(0) = 1), so bounding must be BITWISE equivalent — kernel vs
-    kernel, oracle vs oracle — whenever the bound covers the causal range.
+    """Pages past the causal range would fold in nothing (every position
+    masked: p = 0, corr = exp(0) = 1), and the walk ends at the causal
+    bound where the caller's is wider, so bounding must be BITWISE
+    equivalent — kernel vs kernel, oracle vs oracle — whenever the bound
+    covers the causal range, whole page groups and partial ones alike.
     """
     ks = jax.random.split(jax.random.key(b * 77 + c + nblk), 5)
     n = b * nblk + 2
@@ -322,7 +327,7 @@ def test_num_live_blocks_spans_one_to_nblk():
     tail where only one block of a wide table is live): the kernel must
     agree with the oracle under the SAME bound, even when the bound cuts
     below the causal range (extra slots = garbage the request must never
-    read — the safety property of the clamped index_maps)."""
+    read — the walk copies only slots below the bound)."""
     b, c, kh, g, d, bs, nblk = 2, 3, 2, 2, 64, 4, 6
     ks = jax.random.split(jax.random.key(11), 4)
     n = b * nblk + 1
@@ -347,8 +352,8 @@ def test_num_live_blocks_spans_one_to_nblk():
 def test_bounded_walk_never_reads_dead_slots():
     """Scribbling NaN over every pool block a request's bound excludes
     must not change its output — the dead slots are truly never read
-    (the DMA-skip safety argument: clamped index_maps only ever name
-    live table slots)."""
+    (the safety argument: the walk copies only table slots below the
+    bound)."""
     b, c, kh, g, d, bs, nblk = 1, 2, 2, 2, 64, 4, 5
     ks = jax.random.split(jax.random.key(29), 3)
     n = nblk + 2
@@ -394,6 +399,120 @@ def test_decode_wrapper_bounded_matches_chunk():
                                rtol=2e-5, atol=2e-5)
 
 
+# ==================================================== live-page walks
+#: lane packings (kh, g, d, bs): small heads on 32-token pages (groups of 4
+#: pages), stablelm-3b's MHA (32 x 80 lanes) and starcoder2-3b's GQA (2 x
+#: 128 lanes, G = 12) on 16-token pages (groups of 8)
+PACKINGS = {"small": (2, 1, 64, 32), "mha-32x80": (32, 1, 80, 16),
+            "gqa-2x128-g12": (2, 12, 128, 16)}
+#: live pages of the real rows: two groups and a tail, one group and a
+#: tail, a tail alone; every serving-shaped case adds a pad row
+LIVE = (11, 6, 3)
+NBLK = 12
+
+
+@pytest.mark.parametrize("bs,lanes,nblk,p", [
+    (16, 2560, 256, 8),   # stablelm-3b: 128 keys, whatever the pool dtype
+    (16, 256, 5, 5),      # never more than the table holds
+    (4, 64, 64, 32),
+    (16, 16384, 256, 2),  # two slots of K and V within the VMEM budget
+    (16, 65536, 256, 1),  # at least one page, whatever its size
+])
+def test_pages_per_group(bs, lanes, nblk, p):
+    assert pages_per_group(bs, lanes, nblk) == p
+
+
+@pytest.mark.parametrize("kh,d,hb", [(32, 80, 8), (2, 128, 1), (2, 64, 2),
+                                     (4, 48, 4), (3, 64, 3)])
+def test_heads_per_block(kh, d, hb):
+    assert heads_per_block(kh, d) == hb
+
+
+def _serving_case(key, c, kh, g, d, bs, quantized=False):
+    """Rows of ``LIVE`` live pages (ragged against the page group) whose
+    last query sits inside their last page, then a pad row as the engine
+    builds it: every slot names the scratch block, one query at position
+    0, ``num_live_blocks`` 1.  Every slot of a real row names its own
+    pool block, live or not."""
+    b = len(LIVE) + 1
+    n = len(LIVE) * NBLK + 1
+    scratch = n - 1
+    ks = jax.random.split(key, 5)
+    q = jax.random.normal(ks[0], (b, c, kh, g, d), jnp.float32)
+    if quantized:
+        kp, vp, ksc, vsc = _int8_pools(ks[1], n, bs, kh, d)
+    else:
+        kp = jax.random.normal(ks[1], (n, bs, kh, d), jnp.float32)
+        vp = jax.random.normal(ks[2], (n, bs, kh, d), jnp.float32)
+        ksc = vsc = None
+    tables = np.full((b, NBLK), scratch, np.int32)
+    tables[:-1] = np.asarray(jax.random.permutation(ks[3], n - 1)).reshape(
+        len(LIVE), NBLK)
+    live = np.array(LIVE + (1,), np.int32)
+    last = live * bs - 1 - np.arange(b) % 3
+    last[-1] = 0
+    qpos = np.maximum(last[:, None] - c + 1 + np.arange(c), 0)
+    qpos[-1] = 0
+    return (q, kp, vp, ksc, vsc, jnp.asarray(tables),
+            jnp.asarray(qpos, jnp.int32), jnp.asarray(live))
+
+
+@pytest.mark.parametrize("c", [1, 3])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_live_page_walk_matches_ref(packing, c):
+    """Ragged rows whose live pages are not a multiple of the page group
+    (full groups, then a partial one page by page) and a pad row, at each
+    lane packing: the kernel matches the oracle, and the pad row attends
+    to its one visible key, row 0 of the scratch page."""
+    kh, g, d, bs = PACKINGS[packing]
+    p = pages_per_group(bs, kh * d, NBLK)
+    assert any(n > p and n % p for n in LIVE) and p > 1
+    q, kp, vp, _, _, tables, qpos, live = _serving_case(
+        jax.random.key(kh * d + c), c, kh, g, d, bs)
+    got = paged_attention_chunk(q, kp, vp, tables, qpos, live,
+                                interpret=True)
+    want = ref.paged_attention_chunk_ref(q, kp, vp, tables, qpos, live)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    scratch = int(tables[-1, 0])
+    np.testing.assert_allclose(
+        np.asarray(got[-1]),
+        np.broadcast_to(np.asarray(vp[scratch, 0])[None, :, None, :],
+                        got.shape[1:]), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_live_page_walk_never_reads_past_the_bound(packing, quantized):
+    """NaN over every pool block a row's table names at or past its live
+    bound — inside the last, partial page group and beyond it — and, in
+    the int8 mode, NaN over those blocks' scales and extreme codes in
+    their pages: the output is bitwise unchanged and finite.  No page the
+    bound excludes is fetched, and no buffer row it would have filled is
+    folded in."""
+    kh, g, d, bs = PACKINGS[packing]
+    q, kp, vp, ksc, vsc, tables, qpos, live = _serving_case(
+        jax.random.key(7 * kh + d), 2, kh, g, d, bs, quantized)
+    out = paged_attention_chunk(q, kp, vp, tables, qpos, live, ksc, vsc,
+                                interpret=True)
+    t = np.asarray(tables)
+    dead = np.ones(kp.shape[0], bool)
+    for row, n in zip(t, np.asarray(live)):
+        dead[row[:n]] = False
+    assert dead.sum() == sum(NBLK - n for n in LIVE)
+    page = jnp.asarray(dead)[:, None, None, None]
+    if quantized:
+        kp, vp = (jnp.where(page, jnp.int8(127), x) for x in (kp, vp))
+        ksc, vsc = (jnp.where(jnp.asarray(dead)[:, None], jnp.nan, x)
+                    for x in (ksc, vsc))
+    else:
+        kp, vp = (jnp.where(page, jnp.nan, x) for x in (kp, vp))
+    poisoned = paged_attention_chunk(q, kp, vp, tables, qpos, live, ksc,
+                                     vsc, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(poisoned))
+    assert np.isfinite(np.asarray(poisoned)).all()
+
+
 # ==================================================== int8 quantized pools
 def _int8_pools(key, n, bs, kh, d):
     """Random int8 code pools + per-(block, kv-head) scales."""
@@ -409,6 +528,7 @@ def _int8_pools(key, n, bs, kh, d):
     (3, 4, 2, 2, 64, 8, 5),     # ragged contexts mid-prompt
     (2, 1, 1, 4, 64, 16, 4),    # C == 1 (decode-as-chunk)
     (1, 8, 2, 1, 128, 4, 7),    # chunk wider than a block
+    (3, 2, 2, 1, 64, 32, 11),   # page groups of 4: full groups + a tail
 ])
 def test_int8_kernel_bitwise_matches_materialized_dequant(b, c, kh, g, d,
                                                           bs, nblk):
@@ -416,9 +536,12 @@ def test_int8_kernel_bitwise_matches_materialized_dequant(b, c, kh, g, d,
     the quantized kernel must be BITWISE identical to materializing the
     dequantized fp32 pools and running the unquantized kernel (int8 ->
     f32 is exact; the scalar multiply is the same single f32 rounding in
-    both paths) — across the same ragged-chunk matrix the bounded-grid
-    tests use.  Against the int8 ORACLE (plain softmax vs flash walk)
-    the standard numeric tolerance applies."""
+    both paths) — across the small-packing cases of the bounded-walk
+    tests.  (At 32 x 80 lanes the interpreter's f32 dots may sum an int8
+    and an f32 pool in different orders, with every scale 1.0 too;
+    test_kv_int8 checks that packing against the int8 oracle.)  Against
+    the int8 ORACLE (plain softmax vs flash walk) the standard numeric
+    tolerance applies."""
     from repro.kernels.quant import dequantize_pool
 
     ks = jax.random.split(jax.random.key(b * 31 + c + nblk), 3)
@@ -468,11 +591,11 @@ def test_int8_num_live_blocks_spans_one_to_nblk():
 
 def test_int8_dead_slot_scales_never_read():
     """NaN-poisoning the scales of every block beyond a request's bound
-    must not change the output: the kernel's scale lookup goes through
-    the SAME clamped table walk as the page fetch, so a dead slot's
-    scale is as unreachable as its bytes (the int8 extension of the
-    DMA-skip safety argument — and the exact property that makes a
-    freed block's stale scale harmless)."""
+    must not change the output: the kernel reads a page's scale through
+    the SAME table entry as the page it copies, so a dead slot's scale is
+    as unreachable as its bytes (the int8 extension of the safety
+    argument — and the exact property that makes a freed block's stale
+    scale harmless)."""
     b, c, kh, g, d, bs, nblk = 1, 2, 2, 2, 64, 4, 5
     ks = jax.random.split(jax.random.key(31), 2)
     n = nblk + 2

@@ -198,6 +198,36 @@ def test_int8_attention_error_under_analytic_bound():
     assert err > 0  # quantization really happened (bound isn't vacuous)
 
 
+@pytest.mark.parametrize("kh,g,d", [(32, 1, 80), (2, 12, 128)])
+def test_int8_kernel_walk_matches_int8_oracle(kh, g, d):
+    """The fused-dequant kernel's live-page walk at both serving lane
+    packings (stablelm-3b's 32 x 80 MHA, starcoder2-3b's 2 x 128 GQA with
+    G = 12), on 16-token pages (page groups of 8): ragged rows of two
+    groups and a tail, one group and a tail, a tail alone, and one live
+    page as a pad row has, against the int8 oracle."""
+    from repro.kernels.paged_attention import paged_attention_chunk
+
+    bs, nblk, c = 16, 12, 2
+    live = np.array([11, 9, 3, 1], np.int32)
+    b, n = len(live), len(live) * nblk
+    ks = jax.random.split(jax.random.key(kh + g), 5)
+    q = jax.random.normal(ks[0], (b, c, kh, g, d), jnp.float32)
+    kq = jax.random.randint(ks[1], (n, bs, kh, d), -127, 128, jnp.int8)
+    vq = jax.random.randint(ks[2], (n, bs, kh, d), -127, 128, jnp.int8)
+    k_sc = jax.random.uniform(ks[3], (n, kh), jnp.float32, 0.005, 0.05)
+    v_sc = jax.random.uniform(ks[4], (n, kh), jnp.float32, 0.005, 0.05)
+    tables = jnp.arange(n, dtype=jnp.int32).reshape(b, nblk)
+    qpos = jnp.asarray(np.maximum(
+        live[:, None] * bs - 1 - np.arange(c)[::-1], 0), jnp.int32)
+    nl = jnp.asarray(live)
+    got = paged_attention_chunk(q, kq, vq, tables, qpos, nl, k_sc, v_sc,
+                                interpret=True)
+    want = ref.paged_attention_chunk_int8_ref(q, kq, vq, k_sc, v_sc, tables,
+                                              qpos, nl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ============================================ prefix cache (satellite)
 def test_int8_cached_prefill_logits_exact(dense_model):
     """test_cached_prefill_logits_exact, int8 mode: a tail chunk over

@@ -5,8 +5,9 @@
   that finds only held rows counts nothing;
 * the engine's per-step counts match the shapes it hands the device:
   ``rows_dispatched`` is ``max_batch`` per decode step,
-  ``kv_blocks_walked`` is rows x the bucketed width, ``kv_blocks_live`` is
-  the ``num_live_blocks`` that ``paged_model`` derives for the real rows;
+  ``kv_blocks_walked`` is the ``num_live_blocks`` that ``paged_model``
+  derives for every dispatched row (the pages the kernel fetches; a pad
+  row's is 1), ``kv_blocks_live`` the same for the real rows;
   the phase timers run, and the idle loop's drain is counted;
 * a profiler trace of ``ServeRuntime`` workers holds the ``serve.*``
   spans, each step's dispatch before its complete on the worker's thread.
@@ -131,15 +132,19 @@ def test_engine_counts_match_the_shapes_it_dispatches(dense_model):
     assert decode_calls
     assert all(t.shape[0] == max_batch for _, t, _ in decode_calls)
     assert st["rows_dispatched"] == sum(t.shape[0] for _, t, _ in calls)
-    assert st["kv_blocks_walked"] == sum(t.shape[0] * t.shape[1]
-                                         for _, t, _ in calls)
-    # paged_model's num_live_blocks for the real rows: through the block
-    # of each row's last query position (pad rows name only the scratch
-    # slot)
-    live = sum(int((last[t[:, 0] != pad_slot] // bs + 1).sum())
-               for _, t, last in calls)
+    # paged_model's num_live_blocks for every dispatched row: through the
+    # block of the row's last query position — the pages the kernel
+    # fetches for it in each layer.  A pad row names only the scratch
+    # slot and fetches its one page.
+    pad = [t[:, 0] == pad_slot for _, t, _ in calls]
+    derived = [last // bs + 1 for _, _, last in calls]
+    assert all((n[p] == 1).all() for n, p in zip(derived, pad))
+    assert st["kv_blocks_walked"] == sum(int(n.sum()) for n in derived)
+    live = sum(int(n[~p].sum()) for n, p in zip(derived, pad))
     assert st["kv_blocks_live"] == live
     assert 0 < st["kv_blocks_live"] < st["kv_blocks_walked"]
+    # far below the (rows x width) grid the kernel no longer walks
+    assert st["kv_blocks_walked"] < sum(t.size for _, t, _ in calls)
     assert st["decode_rows"] == sum(
         len(p.requests) if p.kind == "decode" else p.n_decode
         for p in plans)
