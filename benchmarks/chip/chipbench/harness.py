@@ -568,18 +568,32 @@ class Session:
         self.rec.record = self.traced
         if spec["loop"] == "open":
             client.start(t0)
-        if self.traced:
-            lo = t0 + max(0.0, seconds / 2 - TRACE_SECONDS / 2)
-            time.sleep(max(0.0, lo - time.perf_counter()))
-            shutil.rmtree(TRACE_DIR, ignore_errors=True)
-            jax.profiler.start_trace(str(TRACE_DIR))
-            with jax.profiler.TraceAnnotation("bench.trace_window"):
-                time.sleep(min(TRACE_SECONDS, seconds))
-            jax.profiler.stop_trace()
-        time.sleep(max(0.0, t_end - time.perf_counter()))
-        after = sched.stats
-        self.rec.record = False
-        self.counting.clear()
+        # the counters, the step records and the compile count close at
+        # t_end, also where stop_trace, which writes the profile, returns
+        # later than that
+        end: Dict[str, dict] = {}
+
+        def close() -> None:
+            time.sleep(max(0.0, t_end - time.perf_counter()))
+            end["after"] = sched.stats
+            self.rec.record = False
+            self.counting.clear()
+
+        closer = threading.Thread(target=close, name="bench-window-end",
+                                  daemon=True)
+        closer.start()
+        try:
+            if self.traced:
+                lo = t0 + max(0.0, seconds / 2 - TRACE_SECONDS / 2)
+                time.sleep(max(0.0, lo - time.perf_counter()))
+                shutil.rmtree(TRACE_DIR, ignore_errors=True)
+                jax.profiler.start_trace(str(TRACE_DIR))
+                with jax.profiler.TraceAnnotation("bench.trace_window"):
+                    time.sleep(min(TRACE_SECONDS, seconds))
+                jax.profiler.stop_trace()
+        finally:
+            closer.join()
+        after = end["after"]
         client.join()
         recs = [r for r in client.sent() if r.due < t_end]
         return {"recs": recs, "t0": t0, "t_end": t_end, "seconds": seconds,

@@ -7,8 +7,9 @@ else works on those records, so the tests can hand-build them.  Device
 events sit on planes named ``/device:TPU:<n>``: ``XLA Modules`` holds one
 event per jitted step (named after the jitted function, ``_decode`` or
 ``_prefill``), ``XLA Ops`` one per operation, kernels among them under
-their own names.  Host spans are the benchmark's
-``jax.profiler.TraceAnnotation`` s, all named ``bench.<what>``.
+their own names.  Host spans are ``jax.profiler.TraceAnnotation`` s:
+the benchmark's, named ``bench.<what>``, and the program's, named
+``serve.<what>`` (its serving loop's phases on each worker's thread).
 """
 
 from __future__ import annotations
@@ -25,6 +26,8 @@ OPS_LINE = "XLA Ops"
 #: the program's jitted step functions and its paged kernel, by name
 MODULE_KINDS = (("_decode", "decode"), ("_prefill", "prefill"))
 KERNEL = "paged_attention_chunk"
+#: the host spans kept: the benchmark's own and the program's
+HOST_SPANS = ("bench.", "serve.")
 
 
 @dataclass
@@ -48,7 +51,8 @@ def find_xplane(trace_dir: str) -> Optional[str]:
 
 
 def load(path: str) -> List[Event]:
-    """Device events and the benchmark's host spans of one trace."""
+    """Device events and the benchmark's and the program's host spans of
+    one trace."""
     from jax.profiler import ProfileData
 
     out = []
@@ -59,7 +63,7 @@ def load(path: str) -> List[Event]:
                 continue
             for ev in line.events:
                 name = ev.name
-                if not dev and not name.startswith("bench."):
+                if not dev and not name.startswith(HOST_SPANS):
                     continue
                 stats = {}
                 if dev and line.name == OPS_LINE:
@@ -128,7 +132,8 @@ def idle_gaps(events: Sequence[Event], lo: float, hi: float,
               top: int = 10) -> List[Tuple[str, float]]:
     """The longest stretches of ``[lo, hi)`` with no device operation,
     each named by the host span that covers its midpoint (the innermost,
-    i.e. latest-starting, of the benchmark's spans), in seconds."""
+    i.e. latest-starting, of the benchmark's and the program's spans),
+    in seconds."""
     ops = [(e.start_ns, e.end_ns) for e in events
            if is_device(e) and e.line == OPS_LINE]
     busy = union(ops, lo, hi)
@@ -146,7 +151,7 @@ def idle_gaps(events: Sequence[Event], lo: float, hi: float,
         mid = (a + b) / 2
         cover = [e for e in host if e.start_ns <= mid < e.end_ns]
         label = max(cover, key=lambda e: e.start_ns).name if cover \
-            else "no benchmark span"
+            else "no span"
         out.append((re.sub(r"\.\d+$", "", label), (b - a) / 1e9))
     return out
 

@@ -92,6 +92,21 @@ def test_idle_gaps_are_named_by_the_covering_host_span():
                     ("bench.tick", pytest.approx(40e-9))]
 
 
+def test_idle_gaps_are_named_by_the_programs_spans_too():
+    """A gap under a worker's ``serve.idle`` is named by it; the program's
+    spans change no device reading."""
+    bench = [_op("a", 0, 10), _op("b", 50, 60),
+             _host("bench.trace_window", 0, 100)]
+    serve = [_host("serve.tick", 10, 20), _host("serve.idle", 20, 48),
+             _host("serve.idle", 60, 100)]
+    named = dict(trace.idle_gaps(bench + serve, 0, 100))
+    assert named == {"serve.idle": pytest.approx(40e-9)}
+    assert dict(trace.idle_gaps(bench, 0, 100)) == {
+        "no span": pytest.approx(40e-9)}
+    assert trace.busy_ns(bench + serve, 0, 100) == \
+        trace.busy_ns(bench, 0, 100) == 20
+
+
 def test_top_ops_sum_self_time_by_short_name():
     evs = [_op("%fusion.1 = bf16[8] fusion(x)", 0, 10),
            _op("%fusion.1 = bf16[8] fusion(x)", 20, 25),
@@ -136,11 +151,22 @@ def test_a_recorded_cpu_trace_yields_the_benchmark_spans(tmp_path):
     with jax.profiler.TraceAnnotation("bench.trace_window"):
         with jax.profiler.TraceAnnotation("bench.plan.0"):
             f(x).block_until_ready()
+        with jax.profiler.TraceAnnotation("serve.idle"):
+            pass
+        with jax.profiler.TraceAnnotation("other.span"):
+            pass
     jax.profiler.stop_trace()
     events = trace.load(trace.find_xplane(str(tmp_path)))
     lo, hi = trace.span(events, "bench.trace_window")
     plan = trace.span(events, "bench.plan.0")
     assert lo <= plan[0] < plan[1] <= hi
+    # the program's spans are kept beside the benchmark's, no other host
+    # event is
+    idle = trace.span(events, "serve.idle")
+    assert idle is not None and lo <= idle[0] <= idle[1] <= hi
+    assert trace.span(events, "other.span") is None
+    assert all(e.name.startswith(trace.HOST_SPANS) for e in events
+               if not trace.is_device(e))
     # the CPU has no device plane: nothing is counted as device time
     assert trace.busy_ns(events, lo, hi) == 0.0
 

@@ -139,6 +139,50 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch):
         out.checks["max_logit_gap"]["limit"]
 
 
+def test_a_traced_window_closes_its_counters_at_its_end(monkeypatch):
+    """``stop_trace`` returning well past the window's end (a large
+    profile takes seconds to write) leaves the counters' window where it
+    was: their closing snapshot is read at ``t_end``."""
+    import time
+
+    import jax
+    from repro.blocks.scheduler import Scheduler
+
+    late_s = 1.5
+    real_stop, stopped = jax.profiler.stop_trace, []
+
+    def slow_stop():
+        real_stop()
+        time.sleep(late_s)
+        stopped.append(time.perf_counter())
+        # past t_end neither compiles nor steps are counted any more
+        stopped.append((s.counting.is_set(), s.rec.record))
+
+    reads, real_stats = [], Scheduler.stats
+
+    def stats(sched):
+        reads.append(time.perf_counter())
+        return real_stats.fget(sched)
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    monkeypatch.setattr(Scheduler, "stats", property(stats))
+    cell = testing.smoke_cell()
+    s = harness.Session(cell, 2**33 + 17, True, require_accelerator=False)
+    items = traffic.generate(cell.traffic, 2**33 + 17,
+                             cell.config["model"]["vocab_size"], 1.0)
+    reads.clear()
+    try:
+        w = s.window(cell.traffic, 1.0, items)
+    finally:
+        s.runtime.drain(deadline_s=harness.DRAIN_S)
+    # the window's two snapshots: at its start and at its end
+    opened, closed = reads[:2]
+    assert opened <= w["t0"] and stopped[0] >= w["t_end"] + late_s
+    assert w["t_end"] <= closed < w["t_end"] + 0.5
+    assert stopped[1] == (False, False)
+    assert w["counters"]["steps"] > 0
+
+
 def test_a_cell_mix_and_metric_from_new_files_alone(tmp_path):
     """A later change adds a cell by adding a configuration file, a mix
     file, a metric reader and entries: nothing that exists is edited."""
