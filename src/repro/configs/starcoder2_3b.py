@@ -15,7 +15,7 @@ CONFIG = ArchConfig(
     block_pattern=("attn",),
     mlp_kind="gelu",
     norm_kind="layernorm",
-    rope_theta=100_000.0,
+    rope_theta=999_999.4420358813,
     num_microbatches=8,
 )
 

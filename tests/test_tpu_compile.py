@@ -14,6 +14,7 @@ The platform selectors see the CPU backend here, so the tests steer them
 to the TPU branch (``kernels.platform.on_tpu``).
 """
 
+import functools
 import os
 
 import jax
@@ -39,6 +40,17 @@ BS, MAX_BATCH, CHUNK, WIDTH = 16, 8, 128, 128
 #: chip_smoke's step shapes: (rows, query tokens per row)
 SHAPES = {"decode": (MAX_BATCH, 1), "prefill": (1, CHUNK),
           "mixed": (MAX_BATCH + 1, CHUNK)}
+#: the paged kernel's cases, (arch, rows, query tokens per row, table
+#: width): chip_smoke's shapes at stablelm-3b widths (MHA, KH=32, D=80),
+#: and the starcoder2-3b codegen cell's at its GQA packing (KH=2, D=128,
+#: 12 query rows per KV head): 32 rows, requests up to 1,536 + 2,560
+#: tokens -> 256-slot tables
+KERNEL_CASES = {kind: ("stablelm-3b", b, c, WIDTH)
+                for kind, (b, c) in SHAPES.items()}
+KERNEL_CASES.update({
+    "codegen-decode": ("starcoder2-3b", 32, 1, 256),
+    "codegen-prefill": ("starcoder2-3b", 1, CHUNK, 256),
+    "codegen-mixed": ("starcoder2-3b", 33, CHUNK, 256)})
 
 
 @pytest.fixture(scope="module")
@@ -58,20 +70,28 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@functools.cache
+def _served(arch):
+    """An architecture at its published widths with bf16 weights, and the
+    pool a v5e holds beside them: weights resident, 10% headroom (step
+    temporaries are below a megabyte — see the memory test)."""
+    cfg = get_config(arch)
+    cfg = cfg.scaled(param_dtype=cfg.dtype)
+    params = jax.eval_shape(build_model(cfg).init, jax.random.key(0))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    return cfg, pool_blocks_that_fit(V5E_BYTES_LIMIT, weights, 1 << 20,
+                                     pool_block_bytes(cfg, BS))
+
+
 @pytest.fixture(scope="module")
 def published():
-    cfg = get_config("stablelm-3b")
-    return cfg.scaled(param_dtype=cfg.dtype)
+    return _served("stablelm-3b")[0]
 
 
 @pytest.fixture(scope="module")
-def smoke_pool_blocks(published):
-    """The pool the smoke sizes on a v5e: weights resident, 10% headroom
-    (step temporaries are below a megabyte — see the memory test)."""
-    params = jax.eval_shape(build_model(published).init, jax.random.key(0))
-    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    return pool_blocks_that_fit(V5E_BYTES_LIMIT, weights, 1 << 20,
-                                pool_block_bytes(published, BS))
+def smoke_pool_blocks():
+    """The pool the smoke sizes on a v5e."""
+    return _served("stablelm-3b")[1]
 
 
 @pytest.fixture
@@ -96,26 +116,26 @@ def _on(sharding, tree):
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-@pytest.mark.parametrize("kind", sorted(SHAPES))
-def test_paged_kernel_compiles_for_v5e(kind, kv_dtype, one_chip, published,
-                                       smoke_pool_blocks, for_tpu):
-    """The paged kernel at stablelm-3b widths (KH=32, D=80, bs=16) reading
-    the layer-stacked pool the smoke serves from: compiled by Mosaic, and
-    without any copy of the pool (the pool's default layout is the one
-    the kernel reads)."""
-    b, c = SHAPES[kind]
-    kh, d = published.n_kv_heads, published.resolved_head_dim
+@pytest.mark.parametrize("kind", sorted(KERNEL_CASES))
+def test_paged_kernel_compiles_for_v5e(kind, kv_dtype, one_chip, for_tpu):
+    """The paged kernel (bs=16) reading the layer-stacked pool a v5e holds
+    beside the weights of each case's architecture: compiled by Mosaic,
+    and without any copy of the pool (the pool's default layout is the
+    one the kernel reads)."""
+    arch, b, c, width = KERNEL_CASES[kind]
+    cfg, n_blocks = _served(arch)
+    kh, d = cfg.n_kv_heads, cfg.resolved_head_dim
     pools = _on(one_chip, jax.eval_shape(lambda: init_pools(
-        published, smoke_pool_blocks + 1, BS, kv_dtype)))
+        cfg, n_blocks + 1, BS, kv_dtype)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-    q = jax.ShapeDtypeStruct((b, c, kh, 1, d), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((b, c, kh, cfg.n_heads // kh, d), jnp.bfloat16,
                              sharding=one_chip)
     scales = (pools["k_scale"], pools["v_scale"]) if kv_dtype == "int8" \
         else (None, None)
     fn = jax.jit(lambda q, k, v, t, p, n, layer, *sc: _paged_chunk_call(
         q, k, v, t, p, n, layer, *(sc or (None, None)), scale=d ** -0.5,
         interpret=False))
-    args = (q, pools["k"], pools["v"], i32(b, WIDTH), i32(b, c), i32(b),
+    args = (q, pools["k"], pools["v"], i32(b, width), i32(b, c), i32(b),
             i32()) + tuple(s for s in scales if s is not None)
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
